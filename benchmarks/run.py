@@ -19,6 +19,8 @@ import sys
 import time
 import traceback
 
+from repro.launch import compile_cache
+
 MODULES = [
     ("kernels", "benchmarks.bench_kernels"),
     ("dist", "benchmarks.bench_dist"),
@@ -39,6 +41,7 @@ def main(argv=None) -> None:
                     help="comma-separated subset of benchmark keys")
     args = ap.parse_args(argv)
     only = set(args.only.split(",")) if args.only else None
+    compile_cache.enable()
 
     print("name,us_per_call,derived")
     failures = []

@@ -11,8 +11,10 @@ grid — same math, same entrypoint.
 import sys
 
 from repro.apps.als import run_als, run_als_distributed
+from repro.launch import compile_cache
 
 if __name__ == "__main__":
+    compile_cache.enable()
     distributed = "--distributed" in sys.argv[1:]
     runner = run_als_distributed if distributed else run_als
     A, B, hist = runner(m=2048, n=2048, nnz_per_row=12, r=32, rounds=3,

@@ -20,8 +20,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.apps import gat
+from repro.launch import compile_cache
 
 if __name__ == "__main__":
+    compile_cache.enable()
     distributed = "--distributed" in sys.argv[1:]
     serve = "--serve" in sys.argv[1:]
     n, d, heads = 8192, 64, 4

@@ -8,6 +8,9 @@ import jax.numpy as jnp
 
 from repro.core import costmodel, sparse
 from repro.kernels import ops
+from repro.launch import compile_cache
+
+compile_cache.enable()
 
 # 1. build a sparse matrix S (Erdos-Renyi, like the paper's weak scaling)
 m = n = 2048

@@ -23,6 +23,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.launch import compile_cache
+
 
 def decode_demo():
     from repro.config import ParallelConfig
@@ -102,6 +104,7 @@ def serving_demo():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     if "--decode" in sys.argv[1:]:
         decode_demo()
     else:

@@ -14,8 +14,10 @@ from repro.core import costmodel
 from repro.core.sparse_attention import (build_causal_block_mask,
                                          dense_reference, sparsity_stats,
                                          sparse_attention_head)
+from repro.launch import compile_cache
 
 if __name__ == "__main__":
+    compile_cache.enable()
     seq, hd = 1024, 64
     mask = build_causal_block_mask(seq, block=64, window_blocks=2,
                                    global_blocks=1)

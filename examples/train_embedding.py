@@ -13,8 +13,10 @@ instead of the seeded Erdos-Renyi generator.
 import sys
 
 from repro.apps.als import train_embedding_distributed
+from repro.launch import compile_cache
 
 if __name__ == "__main__":
+    compile_cache.enable()
     args = sys.argv[1:]
     kw = dict(m=512, n=512, nnz_per_row=8, r=16, steps=25, lr=0.05)
     if "--mtx" in args:

@@ -13,8 +13,10 @@ import jax
 import numpy as np
 
 from repro.apps import gat
+from repro.launch import compile_cache
 
 if __name__ == "__main__":
+    compile_cache.enable()
     n, d = 512, 16
     graphP = gat.make_dist_graph(n, 6, d, seed=0)
     rng = np.random.default_rng(0)

@@ -11,8 +11,10 @@ results/train_100m.jsonl (see EXPERIMENTS.md).
 import sys
 
 from repro.launch.train import main
+from repro.launch import compile_cache
 
 if __name__ == "__main__":
+    compile_cache.enable()
     sys.exit(main([
         "--arch", "lm-100m", "--steps", "8", "--seq", "128",
         "--batch", "2", "--log-every", "2",

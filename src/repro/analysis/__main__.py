@@ -50,6 +50,7 @@ def main(argv=None) -> int:
         if "XLA_FLAGS" not in os.environ:
             os.environ["XLA_FLAGS"] = (
                 f"--xla_force_host_platform_device_count={args.devices}")
+        os.environ["JAX_PLATFORMS"] = "cpu"   # lowering only: never a chip
         from repro.analysis import conformance
         comms = (args.comm,) if args.comm else ("dense", "sparse")
 

@@ -144,7 +144,14 @@ def dist_fusedmm_matvec(maskP: api.DistProblem, X, B, reg,
                         elision: str = "auto"):
     """y = FusedMM(mask, X, B) + reg*X through the unified API."""
     out, _ = maskP.fusedmm(X, B, elision=elision, session=session)
-    return out + reg * np.asarray(X, np.float32)
+    y = reg * np.asarray(X, np.float32)
+    y += out
+    return y
+
+
+def _row_dots(X, Y):
+    """Per-row dot products as a column, without an (m, r) temporary."""
+    return np.einsum("ij,ij->i", X, Y)[:, None]
 
 
 def dist_cg_solve(maskP: api.DistProblem, B, rhs, reg, iters=10,
@@ -156,19 +163,28 @@ def dist_cg_solve(maskP: api.DistProblem, B, rhs, reg, iters=10,
     replication happens exactly once (first matvec); the iterate X
     changes every iteration and is replicated fresh — never stale.
     """
-    rhs = np.asarray(rhs, np.float32)
-    X = np.zeros_like(rhs)
-    R = rhs - dist_fusedmm_matvec(maskP, X, B, reg, session, elision)
-    P = R
-    rs = np.sum(R * R, axis=1, keepdims=True)
+    X = np.zeros(np.shape(rhs), np.float32)
+    R = np.asarray(rhs, np.float32) - dist_fusedmm_matvec(
+        maskP, X, B, reg, session, elision)
+    del rhs
+    # At real sizes every iterate is gigabytes, so each update makes at
+    # most one new array.  X and P are never changed in place: they went
+    # to the api, whose Session may keep what it uploaded (on the CPU
+    # backend an upload can share the numpy buffer).  R and AP never do.
+    P = R.copy()
+    rs = _row_dots(R, R)
     for _ in range(iters):
         AP = dist_fusedmm_matvec(maskP, P, B, reg, session, elision)
-        alpha = rs / np.maximum(np.sum(P * AP, axis=1, keepdims=True),
-                                1e-12)
-        X = X + alpha * P
-        R = R - alpha * AP
-        rs_new = np.sum(R * R, axis=1, keepdims=True)
-        P = R + (rs_new / np.maximum(rs, 1e-12)) * P
+        alpha = rs / np.maximum(_row_dots(P, AP), 1e-12)
+        step = alpha * P
+        step += X
+        X = step
+        AP *= alpha
+        R -= AP
+        del AP, step
+        rs_new = _row_dots(R, R)
+        P = (rs_new / np.maximum(rs, 1e-12)) * P
+        P += R
         rs = rs_new
     return X
 
@@ -184,12 +200,10 @@ def dist_als_round(dp: DistALSProblem, A, B, cg_iters=10,
     counts, so the cached loop lands on the cheapest cell for the grid
     (docs/choosing.md's worked ALS example).
     """
-    rhs_a = dp.ratings.spmm(B)
-    A = dist_cg_solve(dp.mask, B, rhs_a, dp.reg, cg_iters, session,
-                      elision)
-    rhs_b = dp.ratings_t.spmm(A)
-    B = dist_cg_solve(dp.mask_t, A, rhs_b, dp.reg, cg_iters, session,
-                      elision)
+    A = dist_cg_solve(dp.mask, B, dp.ratings.spmm(B), dp.reg, cg_iters,
+                      session, elision)
+    B = dist_cg_solve(dp.mask_t, A, dp.ratings_t.spmm(A), dp.reg,
+                      cg_iters, session, elision)
     return A, B
 
 
@@ -370,7 +384,6 @@ def train_embedding_distributed(m=256, n=256, nnz_per_row=6, r=16,
 
     def on_failure(attempt, e):
         nonlocal maskP, grad_fn
-        e = faults.unwrap(e)   # typed fault may be XLA-laundered
         session.invalidate(maskP)
         if isinstance(e, faults.DeviceLost):
             maskP = api.degrade(maskP, e.rank)

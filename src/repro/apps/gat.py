@@ -415,7 +415,6 @@ def train_gat_distributed(graphP: api.DistProblem, H, target, *,
 
     def on_failure(attempt, e):
         nonlocal graphP, grad_fn
-        e = faults.unwrap(e)   # typed fault may be XLA-laundered
         session.invalidate(graphP)
         if isinstance(e, faults.DeviceLost):
             graphP = api.degrade(graphP, e.rank)

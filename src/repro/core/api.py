@@ -62,7 +62,7 @@ __all__ = [
     "ALGORITHMS", "Algorithm", "DistProblem", "Session", "SparseResult",
     "make_problem", "sddmm", "spmm", "spmm_t", "fusedmm", "activate",
     "ElasticProblem", "RetryPolicy", "FaultRecoveryError",
-    "RETRYABLE_ERRORS", "problem_from_meta", "degrade", "spmm_batched",
+    "problem_from_meta", "degrade", "spmm_batched",
 ]
 
 
@@ -146,8 +146,7 @@ class SparseResult:
             r, c, v = self.to_coo()
             sk, order = prob.coo_sort()
             idx, ok = _match_coo(sk, order, r * prob.n + c)
-            out = np.zeros(prob.nnz, np.float64)
-            np.add.at(out, idx[ok], v[ok])
+            out = np.bincount(idx[ok], weights=v[ok], minlength=prob.nnz)
             self._vals = out.astype(np.float32)
         return self._vals
 
@@ -321,8 +320,9 @@ def register(cls):
 
 
 def _put(arr, sharding):
-    return jax.device_put(jnp.asarray(np.asarray(arr, np.float32)),
-                          sharding)
+    """Upload host rows straight to their shards: no whole copy of the
+    array passes through the default device first."""
+    return jax.device_put(np.asarray(arr, np.float32), sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -867,8 +867,7 @@ class DistProblem:
         lookup = np.concatenate([np.zeros(1, np.float32), vals])
 
         def inject(pos_arr, old_dev):
-            return jax.device_put(jnp.asarray(lookup[pos_arr]),
-                                  old_dev.sharding)
+            return jax.device_put(lookup[pos_arr], old_dev.sharding)
 
         if isinstance(base.vals, tuple):
             new_vals = tuple(inject(p, o)
@@ -882,8 +881,12 @@ class DistProblem:
         are immutable for a problem's lifetime."""
         if self._coo_sort is None:
             key = self.rows.astype(np.int64) * self.n + self.cols
-            order = np.argsort(key, kind="stable")
-            self._coo_sort = (key[order], order)
+            if np.any(key[1:] < key[:-1]):
+                order = np.argsort(key, kind="stable")
+                key = key[order]
+            else:                      # generators emit row-major COO
+                order = np.arange(len(key))
+            self._coo_sort = (key, order)
         return self._coo_sort
 
     # -- derived problems ----------------------------------------------------
@@ -1476,31 +1479,6 @@ def fusedmm(problem: DistProblem, X, Y, elision: str = "auto",
 # Elastic recovery: typed retry, backoff, degrade-and-re-plan
 # ---------------------------------------------------------------------------
 
-def _runtime_error_types():
-    # the classes a real multi-host jax job raises on device failure;
-    # import-guarded so the api layer never hard-depends on jaxlib layout
-    out = []
-    try:
-        from jax.errors import JaxRuntimeError
-        out.append(JaxRuntimeError)
-    except ImportError:
-        pass
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-        if XlaRuntimeError not in out:
-            out.append(XlaRuntimeError)
-    except ImportError:
-        pass
-    return tuple(out)
-
-
-#: Errors worth retrying: scripted faults from the injection harness and
-#: the runtime's own device-failure surface.  Caller bugs (TypeError,
-#: ValueError, ...) are NOT in this set and propagate immediately.
-RETRYABLE_ERRORS: Tuple[type, ...] = (
-    (faults.TransientFault,) + _runtime_error_types())
-
-
 class FaultRecoveryError(RuntimeError):
     """Recovery budget exhausted: carries the per-attempt fault history
     so post-mortems see every coordinate that fired."""
@@ -1608,17 +1586,17 @@ class ElasticProblem:
     Mirrors the four executor entrypoints; every call runs under the
     typed retry loop:
 
-    * :class:`repro.distributed.faults.TransientFault` / runtime
-      ``XlaRuntimeError`` -> invalidate the Session entries bound to the
-      problem's grid (a failed collective leaves no trustworthy
-      replication state), back off per :class:`RetryPolicy`, retry the
-      round on the same mesh;
+    * :class:`repro.distributed.faults.TransientFault` / a runtime
+      device failure (``faults.retryable``) -> invalidate the Session
+      entries bound to the problem's grid (a failed collective leaves
+      no trustworthy replication state), back off per
+      :class:`RetryPolicy`, retry the round on the same mesh;
     * :class:`repro.distributed.faults.DeviceLost` -> additionally drop
       the lost rank and re-plan the problem from host COO onto the
       largest feasible degraded mesh (:func:`degrade` — cost-model
       re-dispatched), then retry there;
-    * anything else (caller bugs) propagates immediately — retrying a
-      ``TypeError`` can never succeed.
+    * anything else (caller bugs, compile and out-of-memory failures)
+      propagates immediately — retrying it can never succeed.
 
     Results are host-assembled in problem COO order, so a recovered call
     is **bitwise-identical** to a fault-free one on the same mesh, and
@@ -1642,8 +1620,10 @@ class ElasticProblem:
         while True:
             try:
                 return fn(self.problem)
-            except RETRYABLE_ERRORS as e:
+            except faults.RETRYABLE as e:
                 e = faults.unwrap(e)   # typed fault may be XLA-laundered
+                if not faults.retryable(e):
+                    raise              # compile / memory failure: no retry
                 attempt += 1
                 rec = dict(op=label, attempt=attempt, error=repr(e),
                            family=self.problem.alg.name,

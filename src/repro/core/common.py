@@ -26,20 +26,6 @@ import numpy as np
 from repro.core import costmodel
 from repro.core.sparse import RowTiledCOO, pack_row_tiled
 
-try:  # jax >= 0.5 exposes shard_map at the top level with check_vma
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(body, *, mesh, in_specs, out_specs):
-    """Version-portable jax.shard_map with replication checking off."""
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: False})
-
-
 def extract_block(rows, cols, vals, r0, r1, c0, c1):
     """Nonzeros of S falling in the [r0,r1) x [c0,c1) block, rebased."""
     msk = (rows >= r0) & (rows < r1) & (cols >= c0) & (cols < c1)
@@ -96,11 +82,11 @@ def pack_block_list(blocks, shape, row_tile, nz_block, group: int = 1):
     return rl, cl, vl, tb
 
 
-def plan_tiling(tile_base: np.ndarray, *, n_b: int, r: int, k: int,
+def plan_tiling(tile_base: np.ndarray, *, r: int, k: int,
                 row_tile: int) -> costmodel.Tiling:
     """Choose the kernel tiling for a stacked pack at plan time (host)."""
     nb = tile_base.shape[-1]
-    return costmodel.choose_tiling(n_b=n_b, r=r, nb=nb, k=k,
+    return costmodel.choose_tiling(r=r, nb=nb, k=k,
                                    row_tile=row_tile, tile_base=tile_base)
 
 

@@ -619,14 +619,31 @@ def flops_fusedmm(nnz: int, r: int) -> int:
 # Local kernel tiling model (VMEM residency + grid amortization)
 # ---------------------------------------------------------------------------
 
-# Per-core VMEM on current TPUs is ~16 MiB; leave half for Pallas double
-# buffering, semaphores and the compiler's own temporaries.
+# Scoped VMEM per kernel is 16 MiB on v5e; leave half for the pipeline's
+# double buffers, semaphores and the compiler's own temporaries.
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 # Target contraction depth of the one-hot matmul: the MXU is 128x128, so
 # K >= 256 keeps the systolic array busy; beyond ~1024 the gather cost of
 # the nonzero rows dominates.
 _TARGET_STEP_NNZ = 512
+
+#: lane width: DMA slabs of a row are whole multiples of it (or all of r)
+LANES = 128
+
+
+def chunk_nnz(n: int) -> int:
+    """Nonzeros one kernel grid step consumes, for steps of ``n``: the
+    least multiple of ``n`` that fills whole lanes (DESIGN.md §1), so a
+    chunk holds several steps only when ``n`` is below the lane width."""
+    return math.lcm(n, LANES)
+
+
+def kernel_vmem_bytes(*, r_tile: int, chunk: int, row_tile: int,
+                      itemsize: int = 4) -> int:
+    """VMEM a kernel grid step holds: the chunk's gathered B rows plus
+    one A window and one output window, each ``r_tile`` wide."""
+    return (chunk + 2 * row_tile) * r_tile * itemsize
 
 
 @dataclasses.dataclass(frozen=True)
@@ -677,33 +694,36 @@ def groupable_blocks_per_step(tile_base, nz_block: int, *,
     return 1
 
 
-def choose_tiling(*, n_b: int, r: int, nb: int, k: int, row_tile: int,
+def choose_tiling(*, r: int, nb: int, k: int, row_tile: int,
                   itemsize: int = 4,
                   vmem_budget: int = VMEM_BUDGET_BYTES,
                   tile_base=None) -> Tiling:
     """Pick (r_tile, blocks_per_step) from VMEM budget and pack statistics.
 
-    The dominant VMEM resident per grid step is the local B tile slab
-    (n_b x r_tile) plus one (row_tile x r_tile) window each for the
-    gathered-A / accumulator sides, all double-buffered by the Pallas
-    pipeline.  r_tile is the largest divisor of r that fits; the lane width
-    (128) is preferred as a lower bound so slabs stay MXU-aligned.
+    B stays in HBM and only the rows a grid step names are gathered, so
+    VMEM per step (:func:`kernel_vmem_bytes`) grows with the chunk and
+    ``r_tile``, never with the row count of B.  r_tile is ``r`` when that
+    fits, else the largest multiple of the lane width dividing r that
+    does (row DMAs move whole 128-lane slabs).
 
     blocks_per_step amortizes grid/dispatch overhead for small-k packs and
     deepens the one-hot matmul contraction; it is only raised when a
     concrete ``tile_base`` proves the pack groupable (traced packs fall
     back to 1 — distributed planners pass pack stats at plan time).
     """
-    per_col = 2 * (n_b + 2 * row_tile) * itemsize  # x2: double buffering
-    r_tile = r
-    for d in _divisors_desc(r):
-        r_tile = d
-        if d * per_col <= vmem_budget or d <= 128:
-            break
     if tile_base is None:
         bps = 1
     else:
         bps = groupable_blocks_per_step(tile_base, k)
         if nb % bps:
             bps = 1
+    chunk = chunk_nnz(bps * k)
+    r_tile = r
+    for d in _divisors_desc(r):
+        if d != r and d % LANES:
+            continue
+        r_tile = d
+        if kernel_vmem_bytes(r_tile=d, chunk=chunk, row_tile=row_tile,
+                             itemsize=itemsize) <= vmem_budget:
+            break
     return Tiling(r_tile=r_tile, blocks_per_step=bps)

@@ -125,7 +125,6 @@ def plan_d15(grid: Grid15, rows, cols, vals, m: int, n: int, r: int, *,
     rls, cls, vls, tbs, tilings = [], [], [], [], []
     row_off = np.zeros((L, L, c), np.int64)   # (phase, layer, fiber)
     col_off = np.zeros((L, L, c), np.int64)
-    n_dense = cmA if transpose else nB        # rows of the gathered/shifted
     sparse_comm = comm == "sparse"
     # comm="sparse" support sets, in pre-swap coordinates: the gathered
     # operand T is always indexed by S's row axis (block-local, [0,cmA))
@@ -152,7 +151,7 @@ def plan_d15(grid: Grid15, rows, cols, vals, m: int, n: int, r: int, *,
                 blocks.append((br, bc, bv))
         rl, cl, vl, tb = common.pack_block_list(blocks, blk_shape, row_tile,
                                                 nz_block, group=group)
-        tilings.append(common.plan_tiling(tb, n_b=n_dense, r=r,
+        tilings.append(common.plan_tiling(tb, r=r,
                                           k=nz_block, row_tile=row_tile))
         shp = (L, c) + rl.shape[1:]
         rls.append(jax.device_put(rl.reshape(shp), sh5))
@@ -271,11 +270,11 @@ def _exec(grid: Grid15, plan: PlanD15, body, A, B, out_specs,
     s_pack = (plan.rows_local, plan.cols, plan.vals, plan.tile_base)
     s_specs = jax.tree_util.tree_map(lambda _: s_spec, s_pack)
     sup_specs = jax.tree_util.tree_map(lambda _: s_spec, plan.sup)
-    fn = common.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(s_specs, a_spec if a_spec is not None else P((lay, fib)),
                   P((lay, fib)), sup_specs),
-        out_specs=out_specs)
+        out_specs=out_specs, check_vma=False)
     return fn(s_pack, A, B, plan.sup)
 
 

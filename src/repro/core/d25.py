@@ -111,7 +111,7 @@ def plan_d25(grid: Grid25, rows, cols, vals, m: int, n: int, r: int, *,
                 blocks.append((br, bc, bv))
     rl, cl, vl, tb = common.pack_block_list(blocks, blk_shape, row_tile,
                                             nz_block, group=group)
-    tiling = common.plan_tiling(tb, n_b=mS if transpose else nS, r=rW,
+    tiling = common.plan_tiling(tb, r=rW,
                                 k=nz_block, row_tile=row_tile)
     sh = grid.sharding("row", "col", "fiber")
     shp = (G, G, c) + rl.shape[1:]
@@ -261,12 +261,12 @@ def _exec(grid: Grid25, plan: PlanD25, body, A, B_sk, out_specs,
     rw, cl_ax, fib = grid.row, grid.col, grid.fiber
     s_spec = P(rw, cl_ax, fib)
     sup_specs = jax.tree_util.tree_map(lambda _: s_spec, plan.sup)
-    fn = common.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=((s_spec,) * 4,
                   a_spec if a_spec is not None else P((rw, fib), cl_ax),
                   s_spec, sup_specs),
-        out_specs=out_specs)
+        out_specs=out_specs, check_vma=False)
     s_pack = (plan.rows_local, plan.cols, plan.vals, plan.tile_base)
     return fn(s_pack, A, B_sk, plan.sup)
 

@@ -108,7 +108,7 @@ def plan_s15(grid: Grid15, rows, cols, vals, m: int, n: int, r: int, *,
             row_off.append(b * mS)
     rl, cl, vl, tb = common.pack_block_list(blocks, (mS, n), row_tile,
                                             nz_block, group=group)
-    tiling = common.plan_tiling(tb, n_b=n, r=r * c // p, k=nz_block,
+    tiling = common.plan_tiling(tb, r=r * c // p, k=nz_block,
                                 row_tile=row_tile)
     sh = grid.sharding("layer", "fiber")
     shp = (L, c) + rl.shape[1:]
@@ -184,13 +184,13 @@ def _exec(grid: Grid15, plan: PlanS15, body, A, B, out_specs,
     mesh, lay, fib = grid.mesh, grid.layer, grid.fiber
     s_spec = P(lay, fib)
     sup_specs = jax.tree_util.tree_map(lambda _: s_spec, plan.sup)
-    fn = common.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=((s_spec,) * 4,
                   a_spec if a_spec is not None else P(None, (lay, fib)),
                   b_spec if b_spec is not None else P(None, (lay, fib)),
                   sup_specs),
-        out_specs=out_specs)
+        out_specs=out_specs, check_vma=False)
     s_pack = (plan.rows_local, plan.cols, plan.vals, plan.tile_base)
     return fn(s_pack, A, B, plan.sup)
 

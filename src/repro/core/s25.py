@@ -133,7 +133,7 @@ def plan_s25(grid: Grid25, rows, cols, vals, m: int, n: int, r: int, *,
         tb = np.pad(tb, ((0, 0), (0, pad)), mode="edge")
         nb += pad
     k = rl.shape[-1]
-    tiling = common.plan_tiling(tb, n_b=nS, r=rc, k=nz_block,
+    tiling = common.plan_tiling(tb, r=rc, k=nz_block,
                                 row_tile=row_tile)
     # replicate structure across z; shard values by nonzero-block across z
     rl_g = np.broadcast_to(rl[:, None], (G * G, c, nb, k)).reshape(
@@ -245,10 +245,10 @@ def _shift_back(x, axis_name, size):
 def _exec(grid: Grid25, plan: PlanS25, body, A_sk, B_sk, out_specs):
     s_spec = P(grid.row, grid.col, grid.fiber)
     sup_specs = jax.tree_util.tree_map(lambda _: s_spec, plan.sup)
-    fn = common.shard_map(
+    fn = jax.shard_map(
         body, mesh=grid.mesh,
         in_specs=((s_spec,) * 4, s_spec, s_spec, sup_specs),
-        out_specs=out_specs)
+        out_specs=out_specs, check_vma=False)
     s_pack = (plan.rows_local, plan.cols, plan.vals, plan.tile_base)
     return fn(s_pack, A_sk, B_sk, plan.sup)
 

@@ -19,7 +19,9 @@ Two layouts:
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
+import os
 from typing import Tuple
 
 import jax
@@ -139,56 +141,46 @@ def pack_row_tiled(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
     row_tile = min(row_tile, shape[0])
     while shape[0] % row_tile:
         row_tile -= 1
-    order = np.lexsort((cols, rows))
-    rows, cols, vals = rows[order], cols[order], vals[order]
-    nnz = rows.shape[0]
+    rows, cols, vals = np.asarray(rows), np.asarray(cols), np.asarray(vals)
+    key = rows.astype(np.int64) * shape[1] + cols
+    if np.any(key[1:] < key[:-1]):    # generators already emit row-major
+        order = np.argsort(key, kind="stable")
+        rows, cols, vals = rows[order], cols[order], vals[order]
+    del key
+    rows = rows.astype(np.int64)
 
-    def zero_block():
-        return (np.zeros(nz_block, np.int32), np.zeros(nz_block, np.int32),
-                np.zeros(nz_block, np.float32))
-
-    blk_rows, blk_cols, blk_vals, bases = [], [], [], []
-    i = 0
-    while i < nnz:
-        base = (int(rows[i]) // row_tile) * row_tile
-        # all nonzeros in [base, base+row_tile) starting at i
-        hi = int(np.searchsorted(rows, base + row_tile, side="left"))
-        run = 0
-        while i < hi:
-            j = min(i + nz_block, hi)
-            n = j - i
-            lr, lc, lv = zero_block()
-            lr[:n] = rows[i:j] - base
-            lc[:n] = cols[i:j]
-            lv[:n] = vals[i:j]
-            blk_rows.append(lr); blk_cols.append(lc); blk_vals.append(lv)
-            bases.append(base)
-            run += 1
-            i = j
-        while run % group:           # pad the window run to a group boundary
-            lr, lc, lv = zero_block()
-            blk_rows.append(lr); blk_cols.append(lc); blk_vals.append(lv)
-            bases.append(base)
-            run += 1
-
-    nb = len(bases)
+    # one pass, vectorized: every nonzero's window, its rank inside the
+    # window, and from those its (block, slot); each window's run of
+    # blocks is padded to a multiple of ``group``
+    win = rows // row_tile
+    first = np.flatnonzero(np.diff(win, prepend=-1))   # windows are sorted
+    wins = win[first]
+    count = np.diff(first, append=len(win))
+    run = -(-count // nz_block)
+    run = -(-run // group) * group
+    start = np.cumsum(run) - run                    # first block per window
+    # flat slot of every nonzero: its window's first slot plus its rank
+    shift = np.repeat((start * nz_block - first), count)
+    flat = np.arange(len(rows)) + shift
+    nb = int(run.sum())
     target = nblocks if nblocks is not None else max(nb, 1)
     target = _round_up(target, group)
     if nb > target:
         raise ValueError(f"needs {nb} blocks > target {target}")
     # Padding blocks inherit the last real base so the sequence of output
-    # tiles stays non-decreasing (Pallas requires consecutive revisits).
-    pad_base = bases[-1] if bases else 0
-    for _ in range(target - nb):
-        blk_rows.append(np.zeros(nz_block, np.int32))
-        blk_cols.append(np.zeros(nz_block, np.int32))
-        blk_vals.append(np.zeros(nz_block, np.float32))
-        bases.append(pad_base)
-
-    return RowTiledCOO(
-        jnp.asarray(np.stack(blk_rows)), jnp.asarray(np.stack(blk_cols)),
-        jnp.asarray(np.stack(blk_vals)), jnp.asarray(np.array(bases, np.int32)),
-        shape, row_tile)
+    # tiles stays non-decreasing (the kernels visit windows in order).
+    bases = np.repeat(wins * row_tile, run).astype(np.int32)
+    pad_base = bases[-1] if nb else 0
+    bases = np.concatenate(
+        [bases, np.full(target - nb, pad_base, np.int32)])
+    rl = np.zeros((target, nz_block), np.int32)
+    cl = np.zeros((target, nz_block), np.int32)
+    vl = np.zeros((target, nz_block), np.float32)
+    rl.reshape(-1)[flat] = rows - win * row_tile
+    cl.reshape(-1)[flat] = cols
+    vl.reshape(-1)[flat] = vals
+    return RowTiledCOO(jnp.asarray(rl), jnp.asarray(cl), jnp.asarray(vl),
+                       jnp.asarray(bases), shape, row_tile)
 
 
 # ---------------------------------------------------------------------------
@@ -233,23 +225,44 @@ def random_problem(m: int, n: int, r: int, nnz_per_row: int, *,
     return rows, cols, vals, X, Y
 
 
+_RMAT_CHUNK = 1 << 20       # edges one thread generates at a time
+
+
 def rmat(scale: int, edge_factor: int = 16, seed: int = 0,
          a: float = 0.57, b: float = 0.19, c: float = 0.19,
          dtype=np.float32):
-    """RMAT power-law generator — surrogate for SuiteSparse web/social graphs."""
-    rng = np.random.default_rng(seed)
+    """RMAT power-law generator — surrogate for SuiteSparse web/social graphs.
+
+    Level ``l`` of edge ``e`` draws the ``l * ne + e``-th uniform of
+    ``default_rng(seed)``; threads fill chunks of edges from copies of
+    the stream advanced to their offsets, so the graph depends on the
+    seed alone.
+    """
     n = 1 << scale
     ne = n * edge_factor
     rows = np.zeros(ne, np.int64)
     cols = np.zeros(ne, np.int64)
-    for lvl in range(scale):
-        r = rng.random(ne)
-        # quadrant probabilities a, b, c, d
-        right = r >= a + b  # col high bit
-        down = ((r >= a) & (r < a + b)) | (r >= a + b + c)
-        rows |= down.astype(np.int64) << lvl
-        cols |= right.astype(np.int64) << lvl
+
+    def stream(offset):
+        bits = np.random.PCG64(seed)
+        bits.advance(offset)
+        return np.random.Generator(bits)
+
+    def fill(lo):
+        hi = min(lo + _RMAT_CHUNK, ne)
+        for lvl in range(scale):
+            r = stream(lvl * ne + lo).random(hi - lo)
+            # quadrant q = how many of a, a+b, a+b+c are <= r: q is 1 or 3
+            # for the row high bit (down), 2 or 3 for the column's (right)
+            q = ((r >= a).view(np.uint8) + (r >= a + b).view(np.uint8)
+                 + (r >= a + b + c).view(np.uint8))
+            rows[lo:hi] |= np.left_shift(q & 1, lvl, dtype=np.int64)
+            cols[lo:hi] |= np.left_shift(q >> 1, lvl, dtype=np.int64)
+
+    with concurrent.futures.ThreadPoolExecutor(os.cpu_count()) as pool:
+        list(pool.map(fill, range(0, ne, _RMAT_CHUNK)))
     key = np.unique(rows * n + cols)
+    rng = stream(scale * ne)
     rows = (key // n).astype(np.int32)
     cols = (key % n).astype(np.int32)
     vals = rng.standard_normal(rows.shape[0]).astype(dtype)

@@ -6,7 +6,7 @@ Design for 1000+ nodes (CPU-simulated here, same control flow on TPU):
   committed checkpoint (atomic rename + _COMMITTED marker).  The launcher
   wraps each step in ``run_step_resilient``: a *retryable* failure
   triggers restore-and-retry with exponential backoff; repeated failures
-  raise after ``max_retries``.  Only errors in :data:`RETRYABLE` are
+  raise after ``max_retries``.  Only errors ``faults.retryable`` admits are
   retried — a retry loop that swallows every ``Exception`` turns caller
   bugs (TypeError, shape mismatch) into silent infinite restores, so
   non-transient errors propagate on the first attempt.
@@ -35,29 +35,10 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.distributed.faults import TransientFault
+from repro.distributed import faults
 from repro.obs import metrics as obs_metrics
 
 
-def _runtime_error_types():
-    """The runtime-side error types a production step can die with."""
-    types = []
-    try:
-        from jax.errors import JaxRuntimeError
-        types.append(JaxRuntimeError)
-    except ImportError:
-        pass
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-        types.append(XlaRuntimeError)
-    except ImportError:
-        pass
-    return tuple(types)
-
-
-#: Errors worth a restore-and-retry: injected faults from the harness and
-#: runtime/collective failures from XLA.  Everything else is a caller bug.
-RETRYABLE = (TransientFault,) + _runtime_error_types()
 
 
 def backoff_delays(max_retries: int, *, base: float = 0.0,
@@ -123,16 +104,17 @@ def remesh(n_devices: int, model_parallel: int):
 
 def run_step_resilient(step_fn, save_fn, restore_fn, *args,
                        max_retries: int = 2, on_failure=None,
-                       retryable=RETRYABLE, backoff=None,
+                       retryable=faults.RETRYABLE, backoff=None,
                        sleep=time.sleep):
     """Execute one training step with restore-and-retry semantics.
 
     step_fn dying with a *retryable* error (injected ``TransientFault``,
-    runtime ``XlaRuntimeError`` from a preempted host or failed
+    runtime ``JaxRuntimeError`` from a preempted host or failed
     collective) triggers ``restore_fn() -> fresh args`` and a retry after
-    an exponential-backoff delay.  Non-retryable errors — TypeErrors,
-    shape mismatches, any caller bug — propagate immediately: retrying
-    them can only loop forever on the same deterministic failure.
+    an exponential-backoff delay; ``on_failure(attempt, err)`` sees the
+    typed fault.  Non-retryable errors — TypeErrors, shape mismatches,
+    compile refusals, out-of-memory, any caller bug — propagate
+    immediately: retrying them can only fail the same way again.
 
     ``backoff`` is an iterable of delays (default: ``backoff_delays``
     with zero base delay, i.e. no sleeping in tests); ``sleep`` is
@@ -146,9 +128,12 @@ def run_step_resilient(step_fn, save_fn, restore_fn, *args,
         try:
             return step_fn(*args)
         except retryable as e:
+            typed = faults.unwrap(e)   # typed fault may be XLA-laundered
+            if not faults.retryable(typed):
+                raise                  # compile / memory failure: no retry
             attempt += 1
             if on_failure:
-                on_failure(attempt, e)
+                on_failure(attempt, typed)
             if attempt > max_retries:
                 raise
             d = next(delays, 0.0)

@@ -23,7 +23,7 @@ A collective failure kills the whole round (exactly as a lost device
 inside an all-gather or ppermute does on real hardware), so the guard
 raises on the host at the round boundary, *before* launching the jitted
 executor — the failure is observed at the same program point a runtime
-``XlaRuntimeError`` would surface.
+``JaxRuntimeError`` would surface.
 
 Faults are **typed**: :class:`TransientFault` models a recoverable hiccup
 (link timeout, preemption — retry on the same mesh succeeds);
@@ -39,8 +39,10 @@ Determinism
 alone; :meth:`FaultController.summary` returns a JSON-ready record of
 every guarded round and every fired fault (the CI artifact).
 
-Nothing here imports jax — the harness is pure host-side bookkeeping and
-costs nothing when no plan is armed.
+The harness is pure host-side bookkeeping and costs nothing when no plan
+is armed.  :data:`RETRYABLE` and :func:`retryable` are the one retry set
+of both recovery loops (``repro.core.api.ElasticProblem`` and
+``repro.distributed.elastic.run_step_resilient``).
 """
 from __future__ import annotations
 
@@ -49,10 +51,12 @@ import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.errors import JaxRuntimeError
 
 __all__ = [
     "TransientFault", "DeviceLost", "FaultSpec", "FaultPlan", "unwrap",
     "FaultController", "inject", "active", "guard", "OPS", "POINTS",
+    "RETRYABLE", "retryable",
 ]
 
 OPS = ("sddmm", "spmm", "spmm_t", "fusedmm")
@@ -237,7 +241,7 @@ def unwrap(e: BaseException) -> BaseException:
 
     A guard firing inside a ``jax.pure_callback`` (the autodiff path
     wraps executors in callbacks) surfaces to the caller as an
-    ``XlaRuntimeError`` — the Python exception type, and with it
+    ``JaxRuntimeError`` — the Python exception type, and with it
     ``DeviceLost.rank``, is lost at the runtime boundary.  The
     controller keeps the typed original in ``last_raised``; this
     reclaims it (once) so recovery code can still dispatch on
@@ -250,6 +254,26 @@ def unwrap(e: BaseException) -> BaseException:
         typed, _ACTIVE.last_raised = _ACTIVE.last_raised, None
         return typed
     return e
+
+
+#: Errors the recovery loops catch: typed faults and runtime failures.
+RETRYABLE = (TransientFault, JaxRuntimeError)
+
+#: Status prefixes of the runtime errors that are device faults: a lost
+#: peer or host, an aborted or timed-out collective.  Anything else — a
+#: compile refusal, an out-of-memory, a bad argument, an unknown status —
+#: fails the same way on every attempt, so it propagates.
+DEVICE_FAULTS = ("UNAVAILABLE", "ABORTED", "DEADLINE_EXCEEDED",
+                 "DATA_LOSS", "CANCELLED")
+
+
+def retryable(e: BaseException) -> bool:
+    """True for a typed fault (after :func:`unwrap`) or a runtime error
+    with a device-fault status; False for everything else."""
+    if isinstance(e, TransientFault):
+        return True
+    return (isinstance(e, JaxRuntimeError)
+            and str(e).startswith(DEVICE_FAULTS))
 
 
 def guard(op: str, problem, elision: str = "none") -> None:

@@ -65,23 +65,3 @@ def fsdp_extend_tree(spec_tree, shape_tree, axis_sizes, data_axis):
         lambda s, x: fsdp_extend_spec(s, x.shape, axis_sizes, data_axis),
         spec_tree, shape_tree,
         is_leaf=lambda v: isinstance(v, P))
-
-
-_ACTIVE_MESH = None
-
-
-def set_mesh(mesh) -> None:
-    """Version-portable ambient-mesh install.
-
-    jax >= 0.6 has ``jax.set_mesh``; older versions get the same effect by
-    entering the Mesh context.  Re-installing (elastic remesh) exits the
-    previously entered context first so the stack doesn't grow unboundedly.
-    """
-    global _ACTIVE_MESH
-    if hasattr(jax, "set_mesh"):
-        jax.set_mesh(mesh)
-        return
-    if _ACTIVE_MESH is not None:
-        _ACTIVE_MESH.__exit__(None, None, None)
-    mesh.__enter__()
-    _ACTIVE_MESH = mesh

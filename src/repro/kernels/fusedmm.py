@@ -1,27 +1,22 @@
 """Pallas TPU kernel for the local FusedMM (SDDMM + SpMM, fused).
 
 This is the paper's "local kernel fusion" primitive [11] adapted to TPU:
-for each nonzero block the sampled dot products are computed and the scaled
-rows of B aggregated into the output window *in one VMEM round trip* — the
+for each nonzero the sampled dot product is computed and the scaled row
+of B aggregated into the output window from *one* DMA of that row — the
 intermediate R never travels to HBM between two kernels.  The sampled
-values are still emitted (cheap, (bps, K) per step) because applications
-such as GAT attention need them; the fusion win is the elided HBM round
-trip and the single propagation round in the distributed algorithm.
+values are still emitted (a ``(1, C)`` block per chunk) because
+applications such as GAT attention need them.
 
-    dots   = rowsum(A[rows] * B[cols])          (VPU)
+    dots   = rowsum(A[rows] * B[cols])                  (MXU, masked)
     coeff  = vals * dots
-    out   += onehot(rows_local) @ (coeff * B[cols])   (MXU)
+    out   += where(onehot(rows_local), coeff, 0) @ B[cols]   (MXU)
 
-VMEM tiling (see DESIGN.md): when the full embedding width r fits the VMEM
-budget (``r_tile == r``) a single 2-D grid step does both halves fused.
-For wider embeddings B enters VMEM in (n_b, r_tile) slabs; the SDDMM
-coefficient then needs *all* slabs before any SpMM contribution, so the
-grid grows a leading phase axis: phase 0 sweeps the slabs accumulating
-partial dots into the R output, phase 1 re-sweeps them scattering
-``R * B`` into the output windows.  R round-trips through HBM once —
-3 words/nnz, negligible next to the dense slab traffic — while B slabs and
-output windows still never exceed the VMEM budget.  ``blocks_per_step``
-(bps) merges same-window nonzero blocks into one step as in spmm/sddmm.
+Tiling (DESIGN.md §1-2): with ``r_tile == r`` one grid axis sweeps the
+chunks, holding ``(C, r)`` gathered rows, one A window and one output
+window in VMEM.  The SDDMM coefficient needs the whole width before any
+SpMM contribution, so for ``r_tile < r`` the kernel is the SDDMM kernel
+followed by the SpMM kernel: R round-trips through HBM once, 3 words per
+nonzero against the dense slab traffic.
 """
 from __future__ import annotations
 
@@ -32,137 +27,95 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _fusedmm_kernel(base_ref, rows_ref, cols_ref, vals_ref, a_ref, b_ref,
-                    acc_ref, out_ref, rvals_ref, *, row_tile):
-    """Single-phase variant: full r resident, one VMEM round trip."""
-    rl = rows_ref[...].reshape(-1)
-    cl = cols_ref[...].reshape(-1)
-    v = vals_ref[...].reshape(-1).astype(jnp.float32)
-    a = a_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
-    a_rows = jnp.take(a, rl, axis=0)                     # (bps*K, r)
-    b_rows = jnp.take(b, cl, axis=0)                     # (bps*K, r)
-    coeff = v * jnp.sum(a_rows * b_rows, axis=-1)        # f32  (SDDMM)
-    scaled = coeff[:, None] * b_rows
-    iota = jax.lax.broadcasted_iota(jnp.int32, (row_tile, rl.shape[0]), 0)
-    onehot = (iota == rl[None, :]).astype(jnp.float32)
-    out_ref[...] += jax.lax.dot(                         # (SpMM)
-        onehot, scaled, preferred_element_type=jnp.float32
-    ).astype(out_ref.dtype)
-    rvals_ref[...] = coeff.reshape(rvals_ref.shape).astype(rvals_ref.dtype)
+from repro.core.costmodel import chunk_nnz
+from repro.kernels import dma
+from repro.kernels.sddmm import sddmm_pallas
+from repro.kernels.spmm import spmm_pallas
 
 
-def _fusedmm2_kernel(base_ref, rows_ref, cols_ref, vals_ref, a_ref, b_ref,
-                     acc_out_ref, acc_rv_ref, out_ref, rvals_ref, *,
-                     row_tile):
-    """Two-phase variant: r tiled into slabs; phase 0 SDDMM, phase 1 SpMM."""
-    ph = pl.program_id(0)
-    rl = rows_ref[...].reshape(-1)
-    cl = cols_ref[...].reshape(-1)
-    b = b_ref[...].astype(jnp.float32)                   # (n_b, r_tile)
-    b_rows = jnp.take(b, cl, axis=0)                     # (bps*K, r_tile)
+def _fusedmm_kernel(base_ref, cols_ref, rows_ref, vals_ref, a_hbm, b_hbm,
+                    out_hbm, rv_ref, b_rows, a_win, acc, cur, sems,
+                    *, row_tile, n, slabs_per_row):
+    i = pl.program_id(0)
 
-    @pl.when(ph == 0)
-    def _sddmm_phase():
-        v = vals_ref[...].astype(jnp.float32)
-        a = a_ref[...].astype(jnp.float32)               # (row_tile, r_tile)
-        a_rows = jnp.take(a, rl, axis=0)
-        dots = jnp.sum(a_rows * b_rows, axis=-1).reshape(v.shape)
-        # accumulation across non-consecutive revisits: the aliased acc
-        # input restores the prior partial into the shared window buffer
-        # on every block-index change (see DESIGN.md §2)
-        rvals_ref[...] += v * dots
+    def out_window(b):
+        return dma.window(out_hbm, b, row_tile, 0, acc)
 
-    @pl.when(ph == 1)
-    def _spmm_phase():
-        coeff = rvals_ref[...].reshape(-1)               # final R (f32, HBM)
-        scaled = coeff[:, None] * b_rows
-        iota = jax.lax.broadcasted_iota(jnp.int32,
-                                        (row_tile, rl.shape[0]), 0)
-        onehot = (iota == rl[None, :]).astype(jnp.float32)
-        out_ref[...] += jax.lax.dot(
-            onehot, scaled, preferred_element_type=jnp.float32
-        ).astype(out_ref.dtype)
+    @pl.when(i == 0)
+    def _sweep():
+        cur[0] = -row_tile
+
+    dma.gather_rows(b_hbm, cols_ref, b_rows, sems.at[1], 0, slabs_per_row)
+    rv_ref[...] = jnp.zeros_like(rv_ref)
+
+    def step(base, hit):
+        def load_a():
+            dma.copy(dma.window(a_hbm, base, row_tile, 0, a_win), a_win,
+                     sems.at[0])
+
+        dma.enter_window(base, cur, acc, out_window, sems.at[0], load_a)
+        h = hit(rows_ref[...], row_tile)
+        coeff = vals_ref[...] * dma.window_dots(h, a_win, b_rows)
+        rv_ref[...] += coeff
+        dma.scatter_rows(acc, h, coeff, b_rows)
+
+    dma.for_each_step(base_ref, n, step)
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _flush():
+        dma.leave_last_window(cur, acc, out_window, sems.at[0],
+                              out_hbm.shape[0])
 
 
 @functools.partial(jax.jit,
                    static_argnames=("row_tile", "m", "r_tile",
                                     "blocks_per_step", "interpret"))
-def fusedmm_pallas(tile_base_blk: jax.Array, rows_local: jax.Array,
+def fusedmm_pallas(tile_base: jax.Array, rows_local: jax.Array,
                    cols: jax.Array, vals: jax.Array, A: jax.Array,
                    B: jax.Array, *, row_tile: int, m: int,
                    r_tile: int | None = None, blocks_per_step: int = 1,
-                   interpret: bool = False):
+                   interpret=False):
     """Returns (out (m,r) f32->B.dtype, r_vals (nblocks, nz_block))."""
     nb, k = rows_local.shape
     r = B.shape[-1]
-    n_b = B.shape[0]
     bps = blocks_per_step
     r_tile = r if r_tile is None else r_tile
     assert m % row_tile == 0, (m, row_tile)
     assert r % r_tile == 0, (r, r_tile)
     assert nb % bps == 0, (nb, bps)
-    out_zeros = jnp.zeros((m, r), jnp.float32)
+    tiling = dict(row_tile=row_tile, r_tile=r_tile, blocks_per_step=bps,
+                  interpret=interpret)
+    if r_tile < r:
+        r_vals = sddmm_pallas(tile_base, rows_local, cols, vals, A, B,
+                              **tiling)
+        return spmm_pallas(tile_base, rows_local, cols, r_vals, B, m=m,
+                           **tiling), r_vals
 
-    if r_tile == r:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(nb // bps,),
-            in_specs=[
-                pl.BlockSpec((bps, k), lambda i, base: (i, 0)),
-                pl.BlockSpec((bps, k), lambda i, base: (i, 0)),
-                pl.BlockSpec((bps, k), lambda i, base: (i, 0)),
-                pl.BlockSpec((row_tile, r),
-                             lambda i, base: (base[i * bps], 0)),   # A
-                pl.BlockSpec((n_b, r), lambda i, base: (0, 0)),     # B
-                pl.BlockSpec((row_tile, r),
-                             lambda i, base: (base[i * bps], 0)),   # acc
-            ],
-            out_specs=[
-                pl.BlockSpec((row_tile, r),
-                             lambda i, base: (base[i * bps], 0)),
-                pl.BlockSpec((bps, k), lambda i, base: (i, 0)),
-            ],
-        )
-        out, r_vals = pl.pallas_call(
-            functools.partial(_fusedmm_kernel, row_tile=row_tile),
-            grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct((m, r), jnp.float32),
-                       jax.ShapeDtypeStruct((nb, k), vals.dtype)],
-            input_output_aliases={6: 0},   # acc zeros -> out (incl. prefetch)
-            interpret=interpret,
-        )(tile_base_blk, rows_local, cols, vals, A, B, out_zeros)
-        return out.astype(B.dtype), r_vals
-
-    rv_zeros = jnp.zeros((nb, k), jnp.float32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(2, r // r_tile, nb // bps),       # phase axis is outermost
-        in_specs=[
-            pl.BlockSpec((bps, k), lambda ph, j, i, base: (i, 0)),
-            pl.BlockSpec((bps, k), lambda ph, j, i, base: (i, 0)),
-            pl.BlockSpec((bps, k), lambda ph, j, i, base: (i, 0)),
-            pl.BlockSpec((row_tile, r_tile),
-                         lambda ph, j, i, base: (base[i * bps], j)),  # A
-            pl.BlockSpec((n_b, r_tile),
-                         lambda ph, j, i, base: (0, j)),              # B slab
-            pl.BlockSpec((row_tile, r_tile),
-                         lambda ph, j, i, base: (base[i * bps], j)),  # acc out
-            pl.BlockSpec((bps, k), lambda ph, j, i, base: (i, 0)),    # acc rv
-        ],
-        out_specs=[
-            pl.BlockSpec((row_tile, r_tile),
-                         lambda ph, j, i, base: (base[i * bps], j)),
-            pl.BlockSpec((bps, k), lambda ph, j, i, base: (i, 0)),
-        ],
-    )
+    n = bps * k
+    c = chunk_nnz(n)
+    rp, _, (Ap, Bp) = dma.lane_pad(r, r, A.astype(jnp.float32),
+                                   B.astype(jnp.float32))
+    u = dma.slab_width(rp)
+    chunked = dma.chunk_operands(tile_base, rows_local, cols, vals, bps)
+    chunks = chunked[1].shape[0]
     out, r_vals = pl.pallas_call(
-        functools.partial(_fusedmm2_kernel, row_tile=row_tile),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((m, r), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, k), jnp.float32)],
-        input_output_aliases={6: 0, 7: 1},     # indices include prefetch arg
+        functools.partial(_fusedmm_kernel, row_tile=row_tile, n=n,
+                          slabs_per_row=rp // u),
+        grid=(chunks,),
+        in_specs=dma.chunk_specs(n, lambda i: i) + [dma.HBM] * 2,
+        out_specs=[dma.HBM,
+                   pl.BlockSpec((None, 1, c), lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((m, rp), jnp.float32),
+                   jax.ShapeDtypeStruct((chunks, 1, c), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((rp // u, c, u), jnp.float32),             # B rows
+            pltpu.VMEM((rp // u, row_tile, u), jnp.float32),      # A window
+            pltpu.VMEM((rp // u, row_tile, u), jnp.float32),      # out window
+            pltpu.SMEM((1,), jnp.int32),                          # its base
+            pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tile_base_blk, rows_local, cols, vals, A, B, out_zeros, rv_zeros)
-    return out.astype(B.dtype), r_vals.astype(vals.dtype)
+    )(*chunked, Ap, Bp.reshape(-1, u))
+    return (out[:, :r].astype(B.dtype),
+            dma.unchunk(r_vals, nb, k).astype(vals.dtype))

@@ -1,12 +1,13 @@
 """Public jit'd wrappers over the Pallas kernels with ref fallback.
 
-``backend="pallas"`` runs the Pallas kernels (interpret mode on CPU, native
-on TPU); ``backend="ref"`` uses the pure-jnp oracles.  The distributed
-algorithms in ``repro.core`` call these for every local kernel invocation,
-so flipping the backend flips the whole system.
+``backend="pallas"`` runs the Pallas kernels (compiled on TPU, interpret
+mode on the CPU backend, refused on any other); ``backend="ref"`` uses the
+pure-jnp oracles.  The distributed algorithms in ``repro.core`` call these
+for every local kernel invocation, so flipping the backend flips the whole
+system.
 
 Tiling knobs (see DESIGN.md): every wrapper accepts ``r_tile`` (width of
-the embedding slab resident in VMEM per grid step) and ``blocks_per_step``
+the embedding slab a grid step moves through VMEM) and ``blocks_per_step``
 (nonzero blocks merged per grid step).  When left ``None`` they default via
 ``costmodel.choose_tiling`` — VMEM-budget-driven for ``r_tile``; pack-stat-
 driven for ``blocks_per_step`` when the pack structure is concrete (inside
@@ -37,7 +38,12 @@ _DIST_ROUTER = None
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compile natively on TPU, interpret on CPU, refuse anything else."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"no Pallas kernels for backend {backend!r}; "
+                           f"use backend='ref'")
+    return backend == "cpu"
 
 
 def set_default_backend(backend: str) -> None:
@@ -46,14 +52,14 @@ def set_default_backend(backend: str) -> None:
     _DEFAULT_BACKEND = backend
 
 
-def _resolve_tiling(S: RowTiledCOO, n_b: int, r: int,
+def _resolve_tiling(S: RowTiledCOO, r: int,
                     r_tile: int | None, blocks_per_step: int | None):
     """Fill unset knobs from the cost model; never inspects traced data."""
     concrete = not isinstance(S.tile_base, jax.core.Tracer)
     derived_bps = False
     if r_tile is None or blocks_per_step is None:
         t = costmodel.choose_tiling(
-            n_b=n_b, r=r, nb=S.nblocks, k=S.nz_block, row_tile=S.row_tile,
+            r=r, nb=S.nblocks, k=S.nz_block, row_tile=S.row_tile,
             tile_base=S.tile_base if concrete else None)
         if r_tile is None:
             r_tile = t.r_tile
@@ -86,9 +92,9 @@ def sddmm(A: jax.Array, B: jax.Array, S: RowTiledCOO,
     backend = backend or _DEFAULT_BACKEND
     if backend == "ref":
         return _ref.sddmm(A, B, S)
-    r_tile, bps = _resolve_tiling(S, B.shape[0], B.shape[-1],
+    r_tile, bps = _resolve_tiling(S, B.shape[-1],
                                   r_tile, blocks_per_step)
-    vals = sddmm_pallas(S.tile_base // S.row_tile, S.rows_local, S.cols,
+    vals = sddmm_pallas(S.tile_base, S.rows_local, S.cols,
                         S.vals, A, B, row_tile=S.row_tile, r_tile=r_tile,
                         blocks_per_step=bps, interpret=_interpret())
     return S.with_vals(vals)
@@ -106,9 +112,9 @@ def spmm(S: RowTiledCOO, B: jax.Array, m: int | None = None,
     backend = backend or _DEFAULT_BACKEND
     if backend == "ref":
         return _ref.spmm(S, B, m)
-    r_tile, bps = _resolve_tiling(S, B.shape[0], B.shape[-1],
+    r_tile, bps = _resolve_tiling(S, B.shape[-1],
                                   r_tile, blocks_per_step)
-    return spmm_pallas(S.tile_base // S.row_tile, S.rows_local, S.cols,
+    return spmm_pallas(S.tile_base, S.rows_local, S.cols,
                        S.vals, B, row_tile=S.row_tile, m=m, r_tile=r_tile,
                        blocks_per_step=bps, interpret=_interpret())
 
@@ -125,9 +131,9 @@ def fusedmm(A: jax.Array, B: jax.Array, S: RowTiledCOO,
     backend = backend or _DEFAULT_BACKEND
     if backend == "ref":
         return _ref.fusedmm(A, B, S, m)
-    r_tile, bps = _resolve_tiling(S, B.shape[0], B.shape[-1],
+    r_tile, bps = _resolve_tiling(S, B.shape[-1],
                                   r_tile, blocks_per_step)
-    out, r_vals = fusedmm_pallas(S.tile_base // S.row_tile, S.rows_local,
+    out, r_vals = fusedmm_pallas(S.tile_base, S.rows_local,
                                  S.cols, S.vals, A, B,
                                  row_tile=S.row_tile, m=m, r_tile=r_tile,
                                  blocks_per_step=bps,

@@ -1,25 +1,20 @@
 """Pallas TPU kernel for blocked SpMM (S @ B) over RowTiledCOO.
 
 The scatter-add of CPU/GPU SpMM is restructured as a one-hot matmul so it
-runs on the MXU: for each nonzero block we gather the K participating rows
-of B, scale by the sample values, and accumulate
+runs on the MXU: per grid step the kernel DMAs the rows of B named by the
+chunk's nonzeros out of HBM, and for each step of the chunk accumulates
 
-    out_window += onehot(rows_local)  @  (vals[:, None] * B[cols])
-      (row_tile x K)                     (K x r_tile)
+    out_window += where(onehot(rows_local), vals, 0)  @  B[cols]
+                   (row_tile x C)                        (C x r_tile)
 
-VMEM tiling (see DESIGN.md): the grid is 2-D, ``(r // r_tile, nb // bps)``
-with the step axis minor.  B enters VMEM as an ``(n_b, r_tile)`` slab that
-stays resident for a whole sweep over the nonzero blocks, so the kernel
-scales to embedding widths far beyond what a whole-B residency allows.
-``blocks_per_step`` (bps) merges that many row-sorted nonzero blocks — all
-sharing one ``tile_base`` window, guaranteed by the packer's ``group``
-option — into a single grid step, deepening the one-hot contraction and
-amortizing per-step dispatch overhead for small-K packs.
-
-Output windows are input/output-aliased to a zeros buffer: on first visit
-the fetched alias initializes the accumulator, on revisits (consecutive
-within a sweep thanks to row-sorted packing) the partial stays resident in
-VMEM; untouched windows remain zero.
+Tiling (DESIGN.md §1-2): the grid is ``(r // r_tile, chunks)`` with the
+chunk axis minor.  B, the window table and the output stay in HBM, so
+VMEM holds only the ``(C, r_tile)`` gathered rows and one
+``(row_tile, r_tile)`` accumulator, and SMEM only the chunk's column
+ids and window bases.  Row-sorted packs visit each window in consecutive
+steps: the accumulator is zeroed on entry and written back on exit, and
+windows no block visits are written as zeros, so every output row is
+written once per r slab.
 """
 from __future__ import annotations
 
@@ -30,60 +25,74 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.costmodel import chunk_nnz
+from repro.kernels import dma
 
-def _spmm_kernel(base_ref, rows_ref, cols_ref, vals_ref, b_ref, acc_ref,
-                 out_ref, *, row_tile):
-    rl = rows_ref[...].reshape(-1)                       # (bps*K,)
-    cl = cols_ref[...].reshape(-1)
-    v = vals_ref[...].reshape(-1).astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)                   # (n_b, r_tile)
-    b_rows = jnp.take(b, cl, axis=0)                     # (bps*K, r_tile)
-    scaled = v[:, None] * b_rows
-    iota = jax.lax.broadcasted_iota(jnp.int32, (row_tile, rl.shape[0]), 0)
-    onehot = (iota == rl[None, :]).astype(jnp.float32)   # (row_tile, bps*K)
-    out_ref[...] += jax.lax.dot(
-        onehot, scaled, preferred_element_type=jnp.float32
-    ).astype(out_ref.dtype)
+
+def _spmm_kernel(base_ref, cols_ref, rows_ref, vals_ref, b_hbm, out_hbm,
+                 b_rows, acc, cur, sems, *, row_tile, n, slabs_per_row):
+    j, i = pl.program_id(0), pl.program_id(1)
+    q = acc.shape[0]
+
+    def out_window(b):
+        return dma.window(out_hbm, b, row_tile, j * q, acc)
+
+    @pl.when(i == 0)
+    def _sweep():
+        cur[0] = -row_tile
+
+    dma.gather_rows(b_hbm, cols_ref, b_rows, sems.at[1], j * q,
+                    slabs_per_row)
+
+    def step(base, hit):
+        dma.enter_window(base, cur, acc, out_window, sems.at[0])
+        dma.scatter_rows(acc, hit(rows_ref[...], row_tile), vals_ref[...],
+                         b_rows)
+
+    dma.for_each_step(base_ref, n, step)
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _flush():
+        dma.leave_last_window(cur, acc, out_window, sems.at[0],
+                              out_hbm.shape[0])
 
 
 @functools.partial(jax.jit,
                    static_argnames=("row_tile", "m", "r_tile",
                                     "blocks_per_step", "interpret"))
-def spmm_pallas(tile_base_blk: jax.Array, rows_local: jax.Array,
+def spmm_pallas(tile_base: jax.Array, rows_local: jax.Array,
                 cols: jax.Array, vals: jax.Array, B: jax.Array, *,
                 row_tile: int, m: int, r_tile: int | None = None,
-                blocks_per_step: int = 1,
-                interpret: bool = False) -> jax.Array:
-    """Returns out (m, r) = S @ B accumulated in f32, cast to B.dtype."""
+                blocks_per_step: int = 1, interpret=False) -> jax.Array:
+    """Returns out (m, r) = S @ B accumulated in f32, cast to B.dtype.
+
+    ``tile_base`` holds each block's first window row (non-decreasing).
+    """
     nb, k = rows_local.shape
     r = B.shape[-1]
-    n_b = B.shape[0]
     bps = blocks_per_step
     r_tile = r if r_tile is None else r_tile
     assert m % row_tile == 0, (m, row_tile)
     assert r % r_tile == 0, (r, r_tile)
     assert nb % bps == 0, (nb, bps)
-    zeros = jnp.zeros((m, r), jnp.float32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        # step axis minor: one B slab stays VMEM-resident per block sweep
-        grid=(r // r_tile, nb // bps),
-        in_specs=[
-            pl.BlockSpec((bps, k), lambda j, i, base: (i, 0)),
-            pl.BlockSpec((bps, k), lambda j, i, base: (i, 0)),
-            pl.BlockSpec((bps, k), lambda j, i, base: (i, 0)),
-            pl.BlockSpec((n_b, r_tile), lambda j, i, base: (0, j)),    # B
-            pl.BlockSpec((row_tile, r_tile),
-                         lambda j, i, base: (base[i * bps], j)),       # acc
-        ],
-        out_specs=pl.BlockSpec((row_tile, r_tile),
-                               lambda j, i, base: (base[i * bps], j)),
-    )
+    n = bps * k
+    rp, r_tile, (Bp,) = dma.lane_pad(r, r_tile, B.astype(jnp.float32))
+    u = dma.slab_width(r_tile)
+    chunked = dma.chunk_operands(tile_base, rows_local, cols, vals, bps)
     out = pl.pallas_call(
-        functools.partial(_spmm_kernel, row_tile=row_tile),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, r), jnp.float32),
-        input_output_aliases={5: 0},   # acc zeros -> out (index incl. prefetch)
+        functools.partial(_spmm_kernel, row_tile=row_tile, n=n,
+                          slabs_per_row=rp // u),
+        grid=(rp // r_tile, chunked[1].shape[0]),
+        in_specs=dma.chunk_specs(n, lambda j, i: i) + [dma.HBM],
+        out_specs=dma.HBM,
+        out_shape=jax.ShapeDtypeStruct((m, rp), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((r_tile // u, chunk_nnz(n), u), jnp.float32),  # B
+            pltpu.VMEM((r_tile // u, row_tile, u), jnp.float32),  # window
+            pltpu.SMEM((1,), jnp.int32),                          # its base
+            pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tile_base_blk, rows_local, cols, vals, B, zeros)
-    return out.astype(B.dtype)
+    )(*chunked, Bp.reshape(-1, u))
+    return out[:, :r].astype(B.dtype)

@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512 " \
     + os.environ.get("XLA_FLAGS", "")
+os.environ["JAX_PLATFORMS"] = "cpu"   # a compile-only tool: never take a chip
 # ^ MUST precede any jax import: device count locks at first backend init.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
@@ -30,7 +31,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.config import (ModelConfig, ParallelConfig, ServeConfig,
                           TrainConfig, get_config)
-from repro.distributed import sharding
 from repro.distributed.sharding import fsdp_extend_tree, sanitize_tree
 from repro.launch.mesh import make_production_mesh
 
@@ -97,7 +97,7 @@ def lower_cell(arch: str, shape: str, *, multi_pod: bool = False,
 
     cfg = get_config(arch)
     mesh = make_production_mesh(multi_pod=multi_pod)
-    sharding.set_mesh(mesh)   # ambient mesh: activation constraints apply
+    jax.set_mesh(mesh)   # ambient mesh: activation constraints apply
     info = SHAPES[shape]
     kind = info["kind"]
     pcfg = ParallelConfig(

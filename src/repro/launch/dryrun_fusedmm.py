@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512 " \
     + os.environ.get("XLA_FLAGS", "")
+os.environ["JAX_PLATFORMS"] = "cpu"   # a compile-only tool: never take a chip
 
 """Dry-run the PAPER'S kernels on the production mesh: distributed FusedMM
 at p=256, dispatched through the unified repro.core.api registry — ANY

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ParallelConfig
-from repro.distributed import sharding
 from repro.distributed.elastic import StepMonitor
 from repro.launch.mesh import make_local_mesh
 from repro.launch.train import resolve_config
@@ -39,7 +38,7 @@ def main(argv=None):
 
     cfg = resolve_config(args.arch, args.smoke)
     mesh = make_local_mesh()
-    sharding.set_mesh(mesh)
+    jax.set_mesh(mesh)
     pcfg = ParallelConfig(compute_dtype="float32")
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     rng = np.random.default_rng(args.seed)

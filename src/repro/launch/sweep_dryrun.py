@@ -13,6 +13,10 @@ per family.
 
   PYTHONPATH=src python -m repro.launch.sweep_dryrun --fusedmm \
       [--fusedmm-m 1048576] [--fusedmm-r 256]
+
+The sweep itself never imports JAX, which would take an accelerator away
+from its children; even the cell list comes from a child.  Every child
+runs on the CPU backend.
 """
 from __future__ import annotations
 
@@ -40,6 +44,9 @@ MICROBATCH = {
 
 ELISIONS = ("none", "reuse", "fused")
 
+#: environment of every child: compile-only work, on the CPU backend
+CHILD_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
+
 
 def fusedmm_cells():
     """The FULL (algo, elision) grid with per-cell support status.
@@ -51,9 +58,13 @@ def fusedmm_cells():
     regenerable from the sweep output.  No per-family branching: a new
     registered algorithm appears here automatically.
     """
-    from repro.core import api
-    return [(name, el, el in api.ALGORITHMS[name].elisions)
-            for name in sorted(api.ALGORITHMS) for el in ELISIONS]
+    code = ("import json; from repro.core import api; "
+            "from repro.launch.sweep_dryrun import ELISIONS; "
+            "print(json.dumps([(n, el, el in api.ALGORITHMS[n].elisions) "
+            "for n in sorted(api.ALGORITHMS) for el in ELISIONS]))")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, check=True, env=CHILD_ENV)
+    return [tuple(c) for c in json.loads(proc.stdout.splitlines()[-1])]
 
 
 def _print_fusedmm_summary(summary_path):
@@ -116,7 +127,7 @@ def run_fusedmm_sweep(args):
         t0 = time.time()
         try:
             proc = subprocess.run(cmd, capture_output=True, text=True,
-                                  timeout=args.timeout)
+                                  timeout=args.timeout, env=CHILD_ENV)
             ok = proc.returncode == 0
             err = proc.stderr[-2000:] if not ok else ""
         except subprocess.TimeoutExpired:
@@ -186,7 +197,8 @@ def main(argv=None):
                 t0 = time.time()
                 try:
                     proc = subprocess.run(cmd, capture_output=True,
-                                          text=True, timeout=args.timeout)
+                                          text=True, timeout=args.timeout,
+                                          env=CHILD_ENV)
                     ok = proc.returncode == 0
                     err = proc.stderr[-2000:] if not ok else ""
                 except subprocess.TimeoutExpired:

@@ -24,7 +24,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.config import ParallelConfig, TrainConfig, get_config
-from repro.distributed import sharding
 from repro.distributed.elastic import StepMonitor, run_step_resilient
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
@@ -71,7 +70,7 @@ def main(argv=None):
 
     cfg = resolve_config(args.arch, args.smoke)
     mesh = make_local_mesh(model=args.model_parallel)
-    sharding.set_mesh(mesh)
+    jax.set_mesh(mesh)
     pcfg = ParallelConfig(remat="none", compute_dtype="float32",
                           param_dtype="float32")
     tcfg = TrainConfig(seq_len=args.seq, global_batch=args.batch,

@@ -13,7 +13,6 @@ import jax, jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import ParallelConfig, TrainConfig
-from repro.distributed import sharding as shmod
 from repro.configs import llama32_1b
 from repro.distributed.elastic import remesh
 from repro.models import model as M
@@ -37,7 +36,7 @@ def shardings(mesh, params):
 
 # --- phase 1: 8 devices (4 data x 2 model)
 mesh8 = remesh(8, model_parallel=2)
-shmod.set_mesh(mesh8)
+jax.set_mesh(mesh8)
 params = M.init_params(cfg, jax.random.PRNGKey(0))
 state = opt.init_opt_state(params)
 psh8, osh8 = shardings(mesh8, params)
@@ -56,7 +55,7 @@ print("phase1 done on 8 devices, loss", loss8)
 
 # --- phase 2: resume on 4 devices (2 data x 2 model) — simulated shrink
 mesh4 = remesh(4, model_parallel=2)
-shmod.set_mesh(mesh4)
+jax.set_mesh(mesh4)
 tree = ckpt.restore(d, 3, {"params": jax.device_get(params),
                            "opt": jax.device_get(state)})
 psh4, osh4 = shardings(mesh4, tree["params"])

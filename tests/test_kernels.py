@@ -243,13 +243,16 @@ def test_grouped_pack_feasibility():
 
 def test_choose_tiling_respects_vmem_budget():
     from repro.core import costmodel
-    t = costmodel.choose_tiling(n_b=1 << 16, r=1024, nb=64, k=256,
-                                row_tile=256,
-                                vmem_budget=8 * 1024 * 1024)
-    assert 1024 % t.r_tile == 0 and t.r_tile < 1024
-    assert 2 * (1 << 16) * t.r_tile * 4 <= 8 * 1024 * 1024 or t.r_tile <= 128
+    budget = 8 * 1024 * 1024
+    t = costmodel.choose_tiling(r=8192, nb=64, k=256, row_tile=256,
+                                vmem_budget=budget)
+    assert 8192 % t.r_tile == 0 and t.r_tile < 8192
+    assert t.r_tile % costmodel.LANES == 0      # whole-lane DMA slabs
+    assert costmodel.kernel_vmem_bytes(
+        r_tile=t.r_tile, chunk=costmodel.chunk_nnz(256),
+        row_tile=256) <= budget
     # small problems keep full-r residency
-    t2 = costmodel.choose_tiling(n_b=256, r=128, nb=8, k=32, row_tile=64)
+    t2 = costmodel.choose_tiling(r=128, nb=8, k=32, row_tile=64)
     assert t2.r_tile == 128
 
 
@@ -268,3 +271,57 @@ def test_packer_roundtrip():
     assert np.all((rg >= base)[mask] & (rg < base + S.row_tile)[mask])
     # tile bases non-decreasing (Pallas revisit requirement)
     assert np.all(np.diff(np.asarray(S.tile_base)) >= 0)
+
+
+# ---------------------------------------------------------------------------
+# TPU-semantics interpreter: uninitialized VMEM reads as NaN, so a kernel
+# that accumulates into a buffer it never initialized fails here on CPU
+# ---------------------------------------------------------------------------
+
+NAN_CASES = [  # (r, r_tile, blocks_per_step)
+    (128, 128, 1),    # whole width, one block per step
+    (256, 128, 1),    # two r slabs
+    (128, 128, 2),    # two blocks per step
+]
+
+
+def _nan_problem(r, bps):
+    """A 64x48 pack whose row window [16, 32) holds no nonzero."""
+    rng = np.random.default_rng(r + bps)
+    rows, cols, vals = sparse.erdos_renyi(64, 48, 4, seed=r + bps)
+    keep = (rows < 16) | (rows >= 32)
+    S = sparse.pack_row_tiled(rows[keep], cols[keep], vals[keep], (64, 48),
+                              row_tile=16, nz_block=16, group=bps)
+    A = jnp.asarray(rng.standard_normal((64, r)), jnp.float32)
+    B = jnp.asarray(rng.standard_normal((48, r)), jnp.float32)
+    return S, A, B
+
+
+@pytest.mark.parametrize("kernel", ["sddmm", "spmm", "fusedmm"])
+@pytest.mark.parametrize("r,r_tile,bps", NAN_CASES)
+def test_kernel_initializes_accumulators(kernel, r, r_tile, bps):
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels.fusedmm import fusedmm_pallas
+    from repro.kernels.sddmm import sddmm_pallas
+    from repro.kernels.spmm import spmm_pallas
+
+    S, A, B = _nan_problem(r, bps)
+    pack = (S.tile_base, S.rows_local, S.cols, S.vals)
+    kw = dict(row_tile=S.row_tile, r_tile=r_tile, blocks_per_step=bps,
+              interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+    if kernel == "sddmm":
+        got = [sddmm_pallas(*pack, A, B, **kw)]
+        want = [ref.sddmm(A, B, S).vals]
+    elif kernel == "spmm":
+        got = [spmm_pallas(*pack, B, m=64, **kw)]
+        want = [ref.spmm(S, B)]
+    else:
+        got = list(fusedmm_pallas(*pack, A, B, m=64, **kw))
+        out, R = ref.fusedmm(A, B, S)
+        want = [out, R.vals]
+    for g, w in zip(got, want):
+        g = np.asarray(g)
+        assert np.isfinite(g).all()
+        np.testing.assert_allclose(g, np.asarray(w), rtol=1e-4, atol=1e-4)
+    if kernel != "sddmm":
+        assert np.all(np.asarray(got[0])[16:32] == 0.0)   # empty window

@@ -230,6 +230,34 @@ def test_resilient_step_does_not_retry_caller_bugs():
     assert calls["n"] == 1
 
 
+@pytest.mark.parametrize("status,retried", [
+    ("UNAVAILABLE: connection to peer lost", True),
+    ("RESOURCE_EXHAUSTED: out of memory allocating 2.00G", False),
+    ("INTERNAL: Mosaic failed to compile TPU kernel", False),
+    ("INTERNAL: during context [hlo verifier]", False),
+    ("UNKNOWN: unrecognized status", False),
+])
+def test_resilient_step_retries_device_faults_only(status, retried):
+    """A runtime failure of the device is retried; a compile or memory
+    failure would fail the same way again, so it propagates at once."""
+    from jax.errors import JaxRuntimeError
+    from repro.distributed.elastic import run_step_resilient
+    calls = {"n": 0}
+
+    def step(x):
+        calls["n"] += 1
+        if calls["n"] == 1:
+            raise JaxRuntimeError(status)
+        return x + 1
+
+    if retried:
+        assert run_step_resilient(step, None, lambda: (41,), 41) == 42
+    else:
+        with pytest.raises(JaxRuntimeError):
+            run_step_resilient(step, None, lambda: (41,), 41)
+    assert calls["n"] == (2 if retried else 1)
+
+
 def test_synthetic_data_deterministic_and_sharded():
     pipe = data.SyntheticLM(1000, 32, 8, seed=5)
     b1 = pipe.batch(3)
