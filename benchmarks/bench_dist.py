@@ -221,9 +221,7 @@ def run(out, json_path=JSON_PATH):
                             meta=dict(bench="dist", m=M, n=N, r=R,
                                       nnz_row=NNZ_ROW))
     out(f"# wrote {path}")
-    arts = obs.write_artifacts(".", "dist", tracer=tracer,
-                               registry=metrics_reg)
-    out(f"# wrote {arts['trace']}")
+    arts = obs.write_artifacts(".", "dist", registry=metrics_reg)
     out(f"# wrote {arts['metrics']}")
 
 

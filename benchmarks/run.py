@@ -9,10 +9,9 @@ Prints ``name,us_per_call,derived`` CSV.  Usage:
     PYTHONPATH=src python -m benchmarks.run [--only fig4,fig7]
 
 Artifacts land in the working directory: ``BENCH_<key>.json`` (perf
-records) and, from the obs-instrumented benches (dist, serving), the
-``TRACE_<key>.json`` / ``METRICS_<key>.json`` pair described in
-docs/observability.md — Perfetto-loadable spans with per-row cost-model
-drift, and the metrics-registry snapshot.
+records) and, from the obs-instrumented benches (dist, serving),
+``METRICS_<key>.json``, the metrics-registry snapshot described in
+docs/observability.md.
 """
 import argparse
 import sys

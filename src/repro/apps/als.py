@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import api, grads, sparse
 from repro.distributed import elastic, faults
 from repro.kernels import ops
@@ -144,6 +145,12 @@ def dist_fusedmm_matvec(maskP: api.DistProblem, X, B, reg,
                         elision: str = "auto"):
     """y = FusedMM(mask, X, B) + reg*X through the unified API."""
     out, _ = maskP.fusedmm(X, B, elision=elision, session=session)
+    with obs.span("als.cg_host"):
+        return _plus_reg(out, X, reg)
+
+
+def _plus_reg(out, X, reg):
+    """The matvec's host part: ``reg*X + out``, as a new array."""
     y = reg * np.asarray(X, np.float32)
     y += out
     return y
@@ -162,30 +169,39 @@ def dist_cg_solve(maskP: api.DistProblem, B, rhs, reg, iters=10,
     B is stationary across the whole solve, so with a Session its fiber
     replication happens exactly once (first matvec); the iterate X
     changes every iteration and is replicated fresh — never stale.
+
+    Each CG step, the starting residual's included, is one FusedMM call
+    and then one host span ``als.cg_host`` around all of the step's host
+    arithmetic, the matvec's ``+ reg*X`` with it.
     """
     X = np.zeros(np.shape(rhs), np.float32)
-    R = np.asarray(rhs, np.float32) - dist_fusedmm_matvec(
-        maskP, X, B, reg, session, elision)
-    del rhs
-    # At real sizes every iterate is gigabytes, so each update makes at
-    # most one new array.  X and P are never changed in place: they went
-    # to the api, whose Session may keep what it uploaded (on the CPU
-    # backend an upload can share the numpy buffer).  R and AP never do.
-    P = R.copy()
-    rs = _row_dots(R, R)
+    out = maskP.fusedmm(X, B, elision=elision, session=session)[0]
+    with obs.span("als.cg_host"):
+        R = np.asarray(rhs, np.float32) - _plus_reg(out, X, reg)
+        del rhs, out
+        # At real sizes every iterate is gigabytes, so each update makes
+        # at most one new array.  X and P are never changed in place: they
+        # went to the api, whose Session may keep what it uploaded (on the
+        # CPU backend an upload can share the numpy buffer).  R and AP
+        # never do.
+        P = R.copy()
+        rs = _row_dots(R, R)
     for _ in range(iters):
-        AP = dist_fusedmm_matvec(maskP, P, B, reg, session, elision)
-        alpha = rs / np.maximum(_row_dots(P, AP), 1e-12)
-        step = alpha * P
-        step += X
-        X = step
-        AP *= alpha
-        R -= AP
-        del AP, step
-        rs_new = _row_dots(R, R)
-        P = (rs_new / np.maximum(rs, 1e-12)) * P
-        P += R
-        rs = rs_new
+        out = maskP.fusedmm(P, B, elision=elision, session=session)[0]
+        with obs.span("als.cg_host"):
+            AP = _plus_reg(out, P, reg)
+            del out
+            alpha = rs / np.maximum(_row_dots(P, AP), 1e-12)
+            step = alpha * P
+            step += X
+            X = step
+            AP *= alpha
+            R -= AP
+            del AP, step
+            rs_new = _row_dots(R, R)
+            P = (rs_new / np.maximum(rs, 1e-12)) * P
+            P += R
+            rs = rs_new
     return X
 
 

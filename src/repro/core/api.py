@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import costmodel, d15, d25, s15, s25
+from repro.core import common, costmodel, d15, d25, s15, s25
 from repro.core.grid import make_grid15, make_grid25
 from repro.distributed import faults
 
@@ -81,6 +81,13 @@ def _metrics_active():
     """The active obs metrics registry, or None (lazy — see above)."""
     from repro.obs import metrics as obs_metrics
     return obs_metrics.active()
+
+
+def _span(name: str, **args):
+    """A host span on the profiler's clock, ``repro.obs.span`` (lazy —
+    see above)."""
+    from repro.obs import spans
+    return spans.span(name, **args)
 
 
 # ---------------------------------------------------------------------------
@@ -231,12 +238,23 @@ class Algorithm:
         raise NotImplementedError
 
     # -- execution (device in, host out) ------------------------------------
+    @staticmethod
+    def _run(call, *call_args):
+        """One call of an executor: ``call`` (a ``_*_call`` adapter)
+        places the operands under the span ``api.put``, the executor is
+        dispatched, and its ``post`` assembles the host result under
+        ``api.assemble``."""
+        with _span("api.put"):
+            fn, args, kwargs, post = call(*call_args)
+        res = fn(*args, **kwargs)
+        with _span("api.assemble"):
+            return post(res)
+
     def sddmm(self, prob, X, Y, session=None) -> SparseResult:
         """R = S * (X Y^T) sampled at nnz(S).  ``session`` serves the
         family's fiber replication of the dense operand(s) from the
         across-call cache (d15/s15/d25; s25 replicates nothing)."""
-        fn, args, kwargs, post = self._sddmm_call(prob, X, Y, session)
-        return post(fn(*args, **kwargs))
+        return self._run(self._sddmm_call, prob, X, Y, session)
 
     def _sddmm_call(self, prob, X, Y, session):
         raise NotImplementedError
@@ -247,8 +265,7 @@ class Algorithm:
         (:meth:`DistProblem.injected_plan`); ``session`` serves the
         dense gather where the family has one (s15 only — the other
         families' SpMM replicates nothing inbound)."""
-        fn, args, kwargs, post = self._spmm_call(prob, Y, vals, session)
-        return post(fn(*args, **kwargs))
+        return self._run(self._spmm_call, prob, Y, vals, session)
 
     def _spmm_call(self, prob, Y, vals, session):
         raise NotImplementedError
@@ -278,17 +295,14 @@ class Algorithm:
         ``vals`` (problem host-COO order) overrides the pack's sample
         values.
         """
-        fn, args, kwargs, post = self._spmm_t_call(prob, A, vals, session)
-        return post(fn(*args, **kwargs))
+        return self._run(self._spmm_t_call, prob, A, vals, session)
 
     def _spmm_t_call(self, prob, A, vals, session):
         raise NotImplementedError
 
     def fusedmm(self, prob, X, Y, elision: str,
                 session: Optional["Session"]):
-        fn, args, kwargs, post = self._fusedmm_call(prob, X, Y, elision,
-                                                    session)
-        return post(fn(*args, **kwargs))
+        return self._run(self._fusedmm_call, prob, X, Y, elision, session)
 
     def lower_fusedmm(self, prob, elision: str,
                       session: Optional["Session"] = None):
@@ -322,7 +336,9 @@ def register(cls):
 def _put(arr, sharding):
     """Upload host rows straight to their shards: no whole copy of the
     array passes through the default device first."""
-    return jax.device_put(np.asarray(arr, np.float32), sharding)
+    a = np.asarray(arr, np.float32)
+    with _span("api.upload", bytes=a.nbytes):
+        return jax.device_put(a, sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -389,7 +405,7 @@ class _D15(Algorithm):
         # replicated, so there is no gather for a session to serve
         plan = prob.injected_plan("normal", vals)
         return (d15.spmma_d15, (prob.grid, plan, self.shard_y(prob, Y)),
-                {}, np.asarray)
+                {}, common.fetch)
 
     def _spmm_t_call(self, prob, A, vals, session):
         # native FusedMMB-half: spmmb on S's transpose pack — which is
@@ -404,7 +420,7 @@ class _D15(Algorithm):
         else:
             a, pre = self.shard_x(prob, A), False
         return (d15.spmmb_d15, (prob.grid, plan, a),
-                dict(pre_gathered=pre), np.asarray)
+                dict(pre_gathered=pre), common.fetch)
 
     def _fusedmm_call(self, prob, X, Y, elision, session):
         grid = prob.grid
@@ -426,7 +442,7 @@ class _D15(Algorithm):
 
         def post(res):
             out, rvals = res
-            return np.asarray(out), SparseResult(
+            return common.fetch(out), SparseResult(
                 prob, rvals, lambda: plan.meta.block_meta.to_triples(
                     plan.rows_local, plan.cols, rvals, plan.tile_base))
 
@@ -470,7 +486,7 @@ class _S15(Algorithm):
 
     def _rvals_triples(self, prob, plan, rv):
         return lambda: plan.meta.block_meta.to_triples(
-            plan.rows_local, plan.cols, np.asarray(rv), plan.tile_base)
+            plan.rows_local, plan.cols, common.fetch(rv), plan.tile_base)
 
     def _words_plan(self, prob, op, elision, session):
         pre = session is not None
@@ -600,7 +616,7 @@ class _D25(Algorithm):
             return SparseResult(prob, rv,
                                 lambda: plan.meta.block_meta.to_triples(
                                     plan.rows_local, plan.cols,
-                                    np.asarray(rv), plan.tile_base))
+                                    common.fetch(rv), plan.tile_base))
 
         return (d25.sddmm_d25,
                 (prob.grid, plan, a,
@@ -614,7 +630,7 @@ class _D25(Algorithm):
         return (d25.spmma_d25,
                 (prob.grid, plan,
                  d25.skew_b(prob.grid, np.asarray(Y, np.float32))),
-                {}, np.asarray)
+                {}, common.fetch)
 
     def _spmm_t_call(self, prob, A, vals, session):
         # native FusedMMB-half on the Cannon grid (see _D15._spmm_t_call
@@ -647,12 +663,12 @@ class _D25(Algorithm):
         def post(res):
             out, rvals = res
             triples = lambda: plan.meta.block_meta.to_triples(  # noqa: E731
-                plan.rows_local, plan.cols, np.asarray(rvals),
+                plan.rows_local, plan.cols, common.fetch(rvals),
                 plan.tile_base)
             if elision == "reuse":
                 return (d25.unskew_out(grid, plan, out),
                         SparseResult(prob, rvals, triples))
-            return np.asarray(out), SparseResult(prob, rvals, triples)
+            return common.fetch(out), SparseResult(prob, rvals, triples)
 
         return (d25.fusedmm_d25, (grid, plan, a, b),
                 dict(elision=elision, pre_gathered=pre), post)
@@ -702,7 +718,8 @@ class _S25(Algorithm):
         def triples():
             g = prob.grid
             G, nb = g.G, plan.rows_local.shape[3]
-            full = np.asarray(rv).reshape(G, G, nb, np.asarray(rv).shape[-1])
+            vals = common.fetch(rv)
+            full = vals.reshape(G, G, nb, vals.shape[-1])
             return plan.meta.block_meta.to_triples(
                 np.asarray(plan.rows_local)[:, :, 0],
                 np.asarray(plan.cols)[:, :, 0], full,
@@ -1129,10 +1146,11 @@ class DistProblem:
         s15 gathers both, s25 nothing)."""
         faults.guard("sddmm", self)
         tr = _tracer_active()
-        if tr is None:
-            return self.alg.sddmm(self, X, Y, session=session)
-        with tr.round(self, "sddmm", session=session):
-            return self.alg.sddmm(self, X, Y, session=session)
+        with _span("api.sddmm", family=self.alg.name, elision="none"):
+            if tr is None:
+                return self.alg.sddmm(self, X, Y, session=session)
+            with tr.round(self, "sddmm", session=session):
+                return self.alg.sddmm(self, X, Y, session=session)
 
     def spmm(self, Y, vals=None,
              session: Optional["Session"] = None) -> np.ndarray:
@@ -1145,10 +1163,11 @@ class DistProblem:
         replicates nothing inbound."""
         faults.guard("spmm", self)
         tr = _tracer_active()
-        if tr is None:
-            return self.alg.spmm(self, Y, vals=vals, session=session)
-        with tr.round(self, "spmm", session=session):
-            return self.alg.spmm(self, Y, vals=vals, session=session)
+        with _span("api.spmm", family=self.alg.name, elision="none"):
+            if tr is None:
+                return self.alg.spmm(self, Y, vals=vals, session=session)
+            with tr.round(self, "spmm", session=session):
+                return self.alg.spmm(self, Y, vals=vals, session=session)
 
     def spmm_t(self, A, vals=None, session: Optional["Session"] = None
                ) -> np.ndarray:
@@ -1160,14 +1179,15 @@ class DistProblem:
         operand (repro.core.grads).  ``session`` replays a cached fiber
         replication of A where the family gathers one (d15/d25/s15)."""
         faults.guard("spmm_t", self)
-        if vals is not None:
-            vals = np.asarray(vals, np.float32)
-        A = np.asarray(A, np.float32)
         tr = _tracer_active()
-        if tr is None:
-            return self.alg.spmm_t(self, A, vals=vals, session=session)
-        with tr.round(self, "spmm_t", session=session):
-            return self.alg.spmm_t(self, A, vals=vals, session=session)
+        with _span("api.spmm_t", family=self.alg.name, elision="none"):
+            if vals is not None:
+                vals = np.asarray(vals, np.float32)
+            A = np.asarray(A, np.float32)
+            if tr is None:
+                return self.alg.spmm_t(self, A, vals=vals, session=session)
+            with tr.round(self, "spmm_t", session=session):
+                return self.alg.spmm_t(self, A, vals=vals, session=session)
 
     def fusedmm(self, X, Y, elision: str = "auto",
                 session: Optional["Session"] = None):
@@ -1180,10 +1200,11 @@ class DistProblem:
         el = self.resolve_elision(elision, session)
         faults.guard("fusedmm", self, elision=el)
         tr = _tracer_active()
-        if tr is None:
-            return self.alg.fusedmm(self, X, Y, el, session)
-        with tr.round(self, "fusedmm", elision=el, session=session):
-            return self.alg.fusedmm(self, X, Y, el, session)
+        with _span("api.fusedmm", family=self.alg.name, elision=el):
+            if tr is None:
+                return self.alg.fusedmm(self, X, Y, el, session)
+            with tr.round(self, "fusedmm", elision=el, session=session):
+                return self.alg.fusedmm(self, X, Y, el, session)
 
     def lower_fusedmm(self, elision: str = "auto",
                       session: Optional["Session"] = None):
@@ -1275,22 +1296,25 @@ class Session:
         The memo holds only WEAK references (no operand pinning) and
         evicts LRU per entry; an id is validated by dereferencing the
         weakref, so id recycling after gc cannot alias a dead entry."""
-        memo_k = (id(problem.grid), problem.alg.name, problem.comm, slot,
-                  id(arr))
-        memo = self._id_memo.get(memo_k)
-        fp = self._cheap_fp(arr)
-        if memo is not None and memo[0]() is arr and memo[2] == fp:
-            self._id_memo.move_to_end(memo_k)
-            return memo[1]
-        key = self._key(problem, arr, slot)
-        try:
-            ref = weakref.ref(arr)
-        except TypeError:
-            return key                     # un-weakref-able: no memo
-        self._id_memo[memo_k] = (ref, key, fp)
-        while len(self._id_memo) > 4 * self._max_entries:
-            self._id_memo.popitem(last=False)
-        return key
+        with _span("api.session_key") as span:
+            memo_k = (id(problem.grid), problem.alg.name, problem.comm,
+                      slot, id(arr))
+            memo = self._id_memo.get(memo_k)
+            fp = self._cheap_fp(arr)
+            hit = memo is not None and memo[0]() is arr and memo[2] == fp
+            span.set_metadata(hit=hit)
+            if hit:
+                self._id_memo.move_to_end(memo_k)
+                return memo[1]
+            key = self._key(problem, arr, slot)
+            try:
+                ref = weakref.ref(arr)
+            except TypeError:
+                return key                 # un-weakref-able: no memo
+            self._id_memo[memo_k] = (ref, key, fp)
+            while len(self._id_memo) > 4 * self._max_entries:
+                self._id_memo.popitem(last=False)
+            return key
 
     def replicate(self, problem: "DistProblem", arr, slot: str):
         key = self._content_key(problem, arr, slot)

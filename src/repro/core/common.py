@@ -106,6 +106,21 @@ def coo_of(rows_local, cols, vals, tile_base, shape, row_tile) -> RowTiledCOO:
     return RowTiledCOO(rows_local, cols, vals, tile_base, shape, row_tile)
 
 
+def fetch(x) -> np.ndarray:
+    """An executor's device result copied to the host.
+
+    ``np.asarray`` alone would first wait for the device to finish it;
+    here the wait and the copy are two host spans on the profiler's
+    clock, ``api.wait`` and ``api.fetch`` (with its ``bytes``), so a
+    trace tells them apart.  ``repro.obs`` is imported lazily (lint rule
+    R1)."""
+    from repro.obs import spans
+    with spans.span("api.wait"):
+        jax.block_until_ready(x)
+    with spans.span("api.fetch", bytes=x.nbytes):
+        return np.asarray(x)
+
+
 def choose_row_tile(height: int, want: int = 256) -> int:
     """Largest divisor of `height` that is <= want (prefers multiples of 8)."""
     t = min(want, height)

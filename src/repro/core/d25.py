@@ -231,7 +231,7 @@ def unskew_out(grid: Grid25, plan: PlanD25, stacked) -> np.ndarray:
     """Invert the skew for B-shaped outputs (FusedMMB): -> (n, r)."""
     G, c = grid.G, grid.c
     nS, rW = plan.meta.nS, plan.meta.rW
-    stacked = np.asarray(stacked)
+    stacked = common.fetch(stacked)
     out = np.zeros((plan.n, plan.r), np.float32)
     for x in range(G):
         for y in range(G):

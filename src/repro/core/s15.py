@@ -535,7 +535,7 @@ def fusedmm_s15(grid: Grid15, plan: PlanS15, A, B, elision: str = "auto",
 def assemble_spmm_out(grid: Grid15, plan: PlanS15, slabs) -> np.ndarray:
     """Host-side reassembly of phase-stacked SpMM slabs into (m, r)."""
     L, c = grid.L, grid.c
-    slabs = np.asarray(slabs)
+    slabs = common.fetch(slabs)
     out = np.zeros((plan.m, plan.r), np.float32)
     w = plan.r * c // grid.p
     for u in range(L):

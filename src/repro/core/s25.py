@@ -222,7 +222,7 @@ def unskew_out(grid: Grid25, plan: PlanS25, stacked) -> np.ndarray:
     """Reassemble A-shaped outputs whose chunks ended in skewed-home spots."""
     G, c = grid.G, grid.c
     mS, rc = plan.mS, plan.rc
-    stacked = np.asarray(stacked)
+    stacked = common.fetch(stacked)
     out = np.zeros((plan.m, plan.r), np.float32)
     for x in range(G):
         for y in range(G):

@@ -1,66 +1,18 @@
-"""Exporters: Chrome-trace/Perfetto JSON, text summaries, artifacts.
-
-``chrome_trace`` renders a :class:`repro.obs.tracer.Tracer` in the
-Chrome trace-event format (load at ``ui.perfetto.dev`` or
-``chrome://tracing``): one track (tid) per rank — the executors are SPMD,
-every rank runs the same schedule, so the round's spans are duplicated
-onto each rank's track with per-device word counts — with event spans
-nested inside round spans by time containment.
+"""Exporters: a text summary of traced rounds, and the metrics artifact.
 
 ``write_artifacts`` fixes the artifact convention consumed by
-``benchmarks/run.py`` and CI: ``TRACE_<tag>.json`` (Perfetto-loadable)
-and ``METRICS_<tag>.json`` (``MetricsRegistry.snapshot()``) in a chosen
-directory.
+``benchmarks/run.py`` and CI: ``METRICS_<tag>.json``
+(``MetricsRegistry.snapshot()``) in a chosen directory.  A timeline of
+the program is a ``jax.profiler`` trace (docs/observability.md).
 """
 from __future__ import annotations
 
-import json
 import os
-from typing import Optional
 
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.tracer import Tracer
 
-__all__ = ["chrome_trace", "round_summary", "write_artifacts"]
-
-
-def chrome_trace(tracer: Tracer) -> dict:
-    """Chrome trace-event JSON for a finished trace (one track per rank)."""
-    ranks = max([r.p for r in tracer.rounds], default=1)
-    ev = [{"name": "process_name", "ph": "M", "pid": 0, "tid": 0,
-           "args": {"name": "repro executors (SPMD; per-device words)"}}]
-    for tid in range(ranks):
-        ev.append({"name": "thread_name", "ph": "M", "pid": 0, "tid": tid,
-                   "args": {"name": f"rank {tid}"}})
-    for r in tracer.rounds:
-        name = f"{r.family}.{r.op}" + (f"[{r.elision}]"
-                                       if r.op == "fusedmm" else "")
-        args = {"family": r.family, "op": r.op, "elision": r.elision,
-                "comm": r.comm, "round": r.round, "p": r.p, "c": r.c,
-                "session": r.session}
-        if r.modeled_words is not None:
-            args["modeled_words"] = r.modeled_words
-        if r.measured_words is not None:
-            args["measured_words"] = r.measured_words["total"]
-        if r.drift is not None:
-            args["drift"] = r.drift
-        if r.error is not None:
-            args["error"] = r.error
-        for tid in range(r.p):
-            ev.append({"name": name, "cat": "round", "ph": "X", "pid": 0,
-                       "tid": tid, "ts": r.t0 * 1e6, "dur": r.dur * 1e6,
-                       "args": args})
-            for s in r.events:
-                a = {"point": s.point, "phase": s.phase}
-                if s.kind is not None:
-                    a["collective"] = s.kind
-                if s.words is not None:
-                    a["modeled_words"] = s.words
-                ev.append({"name": f"{s.point}[{s.phase}]",
-                           "cat": "event", "ph": "X", "pid": 0,
-                           "tid": tid, "ts": s.t0 * 1e6,
-                           "dur": s.dur * 1e6, "args": a})
-    return {"traceEvents": ev, "displayTimeUnit": "ms"}
+__all__ = ["round_summary", "write_artifacts"]
 
 
 def round_summary(tracer: Tracer) -> str:
@@ -83,19 +35,10 @@ def round_summary(tracer: Tracer) -> str:
 
 
 def write_artifacts(out_dir: str, tag: str, *,
-                    tracer: Optional[Tracer] = None,
-                    registry: Optional[MetricsRegistry] = None) -> dict:
-    """Write ``TRACE_<tag>.json`` / ``METRICS_<tag>.json``; returns paths."""
+                    registry: MetricsRegistry) -> dict:
+    """Write ``METRICS_<tag>.json``; returns ``{"metrics": path}``."""
     os.makedirs(out_dir, exist_ok=True)
-    paths = {}
-    if tracer is not None:
-        p = os.path.join(out_dir, f"TRACE_{tag}.json")
-        with open(p, "w") as fh:
-            json.dump(chrome_trace(tracer), fh)
-        paths["trace"] = p
-    if registry is not None:
-        p = os.path.join(out_dir, f"METRICS_{tag}.json")
-        with open(p, "w") as fh:
-            fh.write(registry.to_json())
-        paths["metrics"] = p
-    return paths
+    p = os.path.join(out_dir, f"METRICS_{tag}.json")
+    with open(p, "w") as fh:
+        fh.write(registry.to_json())
+    return {"metrics": p}

@@ -13,10 +13,10 @@ ratio — **cost-model drift**, 1.0 when the closed-form model matches the
 wire exactly.  Support-pruned (``comm="sparse"``) rounds trace without
 modeled words: their volume is data-dependent and drift is undefined.
 
-Timing: the round's wall time is measured; event spans subdivide it
-proportionally to their modeled words (equal split when no model) — a
-*modeled attribution* for visualization, explicitly not a per-collective
-measurement (the jitted round is one XLA program; docs/observability.md).
+Timing: the round's wall time is measured on the host clock.  A jitted
+round is one XLA program, so its events have no host-side times of
+their own; where each collective ran is read from a ``jax.profiler``
+trace of the device, beside the program's host spans (``repro.obs.span``).
 
 Zero-cost when disabled, like ``faults.guard``: no tracer is installed
 by default and the api layer pays one module attribute read per call.
@@ -42,8 +42,6 @@ class EventSpan:
     phase: int
     kind: Optional[str]           # HLO collective, None for compute
     words: Optional[float]        # modeled wire words (None: no model)
-    t0: float = 0.0               # seconds since trace epoch
-    dur: float = 0.0
 
 
 @dataclasses.dataclass
@@ -57,8 +55,7 @@ class RoundSpan:
     c: int
     round: int                    # per-op call counter since tracing began
     session: bool
-    t0: float
-    dur: float
+    dur: float                    # measured seconds of the call
     events: List[EventSpan]
     modeled_words: Optional[float]      # sum of event models (dense only)
     measured_words: Optional[dict]      # wire_words() dict, if measured
@@ -89,7 +86,6 @@ class Tracer:
         self.measure_wire = measure_wire
         self._registry = registry
         self._clock = clock
-        self.epoch = clock()
         self._counts: dict = {}
         self._wire_cache: dict = {}
 
@@ -114,7 +110,7 @@ class Tracer:
         """Span one executor round (called by the api layer)."""
         rnd = self._counts.get(op, 0)
         self._counts[op] = rnd + 1
-        t0 = self._clock() - self.epoch
+        t0 = self._clock()
         err = None
         try:
             yield
@@ -122,10 +118,10 @@ class Tracer:
             err = type(e).__name__
             raise
         finally:
-            dur = self._clock() - self.epoch - t0
-            self._finish(problem, op, elision, session, rnd, t0, dur, err)
+            dur = self._clock() - t0
+            self._finish(problem, op, elision, session, rnd, dur, err)
 
-    def _finish(self, problem, op, elision, session, rnd, t0, dur, err):
+    def _finish(self, problem, op, elision, session, rnd, dur, err):
         events = problem.alg.schedule_events(problem, op, elision)
         words = problem.alg.schedule_words(problem, op, elision,
                                            session=session)
@@ -138,28 +134,13 @@ class Tracer:
                 measured = None         # lowering unsupported: trace on
             if measured is not None and total:
                 drift = measured["total"] / total
-        # modeled-attribution timing: split the round's wall time across
-        # events by modeled words (equal split when there is no model)
-        if words is None:
-            shares = [1.0] * len(events)
-        else:
-            shares = [max(w, 0.0) for *_, w in words]
-        denom = sum(shares) or float(len(events) or 1)
-        if sum(shares) == 0.0:
-            shares = [1.0] * len(events)
-        spans, t = [], t0
-        for i, (point, phase) in enumerate(events):
-            d = dur * shares[i] / denom
-            spans.append(EventSpan(
-                point=point, phase=phase,
-                kind=None if words is None else words[i][2],
-                words=None if words is None else words[i][3],
-                t0=t, dur=d))
-            t += d
+        spans = ([EventSpan(point, phase, None, None)
+                  for point, phase in events] if words is None
+                 else [EventSpan(*w) for w in words])
         self.rounds.append(RoundSpan(
             op=op, family=problem.alg.name, elision=elision,
             comm=problem.comm, p=problem.p, c=problem.c, round=rnd,
-            session=session is not None, t0=t0, dur=dur, events=spans,
+            session=session is not None, dur=dur, events=spans,
             modeled_words=total, measured_words=measured, drift=drift,
             error=err))
         reg = self._registry or _metrics.active()

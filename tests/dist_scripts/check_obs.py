@@ -9,8 +9,7 @@ Four guarantees:
    exactly 1.0; the band only absorbs future backend-legalization noise.
 
 2. **Span accounting** — per-event modeled words sum to the round's
-   modeled total, spans align 1:1 with ``schedule_events``, and event
-   spans tile the round span.
+   modeled total, and event spans align 1:1 with ``schedule_events``.
 
 3. **Zero-cost parity** — the traced FusedMM result is bitwise-identical
    to the untraced call on the same mesh.
@@ -20,8 +19,8 @@ Four guarantees:
    ElasticProblem retry) populates the registry, and its snapshot
    JSON-round-trips exactly.
 
-Writes TRACE_smoke.json + METRICS_smoke.json (the CI observability
-artifacts; load the trace at ui.perfetto.dev) and prints ALL OBS OK.
+Writes METRICS_smoke.json (the CI observability artifact) and prints
+ALL OBS OK.
 """
 import json
 import os
@@ -133,19 +132,9 @@ assert obs.MetricsRegistry.from_snapshot(
     json.loads(json.dumps(snap))).snapshot() == snap, \
     "metrics snapshot does not round-trip"
 
-# --- chrome-trace artifact: one track per rank, events nested ---------------
-ct = obs.chrome_trace(tracer)
-evs = ct["traceEvents"]
-assert evs, "empty trace"
-tids = {e["tid"] for e in evs if e.get("ph") == "X"}
-assert tids == set(range(8)), f"expected one track per rank, got {tids}"
-threads = [e for e in evs if e.get("ph") == "M"
-           and e["name"] == "thread_name"]
-assert len(threads) == 8
-paths = obs.write_artifacts(".", "smoke", tracer=tracer, registry=reg)
-json.load(open(paths["trace"]))          # artifacts must be valid JSON
-json.load(open(paths["metrics"]))
-print("wrote", paths["trace"], "and", paths["metrics"],
-      f"({len(evs)} trace events, {len(reg.series())} metric series)")
+# --- metrics artifact -------------------------------------------------------
+paths = obs.write_artifacts(".", "smoke", registry=reg)
+json.load(open(paths["metrics"]))        # the artifact must be valid JSON
+print("wrote", paths["metrics"], f"({len(reg.series())} metric series)")
 print(obs.round_summary(tracer))
 print("ALL OBS OK")

@@ -174,7 +174,7 @@ def test_obs_traced_smoke_8dev():
     round's measured/modeled wire-word ratio inside [0.99, 1.01] (the
     impl-exact model lands at 1.0000), per-event word sums equal the
     round model, traced results bitwise vs untraced, and the
-    TRACE_smoke.json / METRICS_smoke.json CI artifacts written."""
+    METRICS_smoke.json CI artifact written."""
     out = run_script("check_obs.py")
     assert "ALL OBS OK" in out
     assert "drift=1.0000" in out

@@ -3,6 +3,8 @@
 Fast tier (1 device): the multi-device traced smoke with the drift gate
 lives in tests/dist_scripts/check_obs.py (slow tier).
 """
+import dataclasses
+import gzip
 import json
 
 import jax
@@ -150,15 +152,19 @@ def test_trace_records_round_and_event_spans():
     assert [r.op for r in tr.rounds] == ["sddmm", "fusedmm"]
     r0 = tr.rounds[0]
     assert r0.family == "d15" and r0.comm == "dense" and r0.p == 1
-    assert len(r0.events) == len(
-        prob.alg.schedule_events(prob, "sddmm"))
-    assert r0.dur >= 0 and all(e.dur >= 0 for e in r0.events)
-    # event spans tile the round span (modeled attribution)
-    assert sum(e.dur for e in r0.events) == pytest.approx(r0.dur)
+    # the round's duration is measured; its events carry the schedule's
+    # coordinates and modeled words, and no timing of their own
+    assert r0.dur > 0
+    words = prob.schedule_words("sddmm")
+    assert [(e.point, e.phase, e.kind, e.words) for e in r0.events] == [
+        tuple(w) for w in words]
+    assert {f.name for f in dataclasses.fields(obs.EventSpan)} == {
+        "point", "phase", "kind", "words"}
+    assert r0.modeled_words == sum(e.words for e in r0.events)
     # metrics fed live
     assert reg.value("executor.rounds", op="sddmm", family="d15") == 1
-    assert reg.histogram("executor.round_seconds", op="fusedmm",
-                         family="d15")["count"] == 1
+    h = reg.histogram("executor.round_seconds", op="fusedmm", family="d15")
+    assert h["count"] == 1 and h["max"] == tr.rounds[1].dur
 
 
 def test_trace_is_bitwise_identical_and_counts_rounds():
@@ -235,34 +241,125 @@ def test_trace_context_restores_previous():
 
 
 # ---------------------------------------------------------------------------
-# Export
+# Host spans in a jax.profiler trace
 # ---------------------------------------------------------------------------
 
+def _profiled(tmp_path, fn, perfetto=False):
+    """Run ``fn`` under a jax.profiler session; returns (its result, the
+    program's host spans as (name, start_ns, end_ns, stats) by start)."""
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path), create_perfetto_trace=perfetto):
+        got = fn()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith(("api.", "als."))]
+    return got, sorted(spans, key=lambda sp: (sp[1], -sp[2]))
+
+
+def _inside(outer, spans, name):
+    """The spans called ``name`` that lie within the span ``outer``."""
+    return [sp for sp in spans if sp[0] == name
+            and outer[1] <= sp[1] and sp[2] <= outer[2]]
+
+
+def test_profiler_spans_nest_in_an_api_round(tmp_path):
+    """A s15 FusedMM with a Session: the round holds the operand
+    placement (the Session's content keys and the uploads) and then the
+    assembly (the wait for the device and the copy back), with the
+    uploaded and fetched bytes as stats."""
+    prob, X, Y = _problem(algorithm="s15")
+    sess = api.Session()
+    (out, _), spans = _profiled(
+        tmp_path, lambda: prob.fusedmm(X, Y, elision="fused", session=sess))
+    (rnd,) = [sp for sp in spans if sp[0] == "api.fusedmm"]
+    assert rnd[3] == {"family": "s15", "elision": "fused"}
+    (put,) = _inside(rnd, spans, "api.put")
+    (asm,) = _inside(rnd, spans, "api.assemble")
+    assert put[2] <= asm[1]
+    keys = _inside(put, spans, "api.session_key")
+    assert [k[3]["hit"] for k in keys] == [0, 0]     # a new Session misses
+    ups = _inside(put, spans, "api.upload")
+    assert sorted(u[3]["bytes"] for u in ups) == sorted([X.nbytes, Y.nbytes])
+    (wait,) = _inside(asm, spans, "api.wait")
+    (fetch,) = _inside(asm, spans, "api.fetch")
+    assert wait[2] <= fetch[1]
+    assert fetch[3]["bytes"] == out.nbytes           # p = 1: the whole output
+    # the sampled values stay on the device: nothing else is fetched
+    assert len([sp for sp in spans if sp[0] == "api.fetch"]) == 1
+
+
+def _cg(prob, X, Y, session):
+    """Two CG steps of the normal equations on the problem's pattern as a
+    mask (positive definite)."""
+    from repro.apps import als
+    mask = prob.with_values(np.ones_like(prob.vals))
+    return als.dist_cg_solve(mask, Y, X, 0.1, iters=2, session=session)
+
+
+def test_profiler_spans_of_a_cg_solve(tmp_path):
+    """``dist_cg_solve`` opens ``als.cg_host`` once per CG step, the
+    starting residual's included, each after that step's FusedMM round;
+    the Session's second key of the fixed factor hits its memo."""
+    prob, X, Y = _problem(algorithm="s15")
+    sess = api.Session()
+    _, spans = _profiled(tmp_path, lambda: _cg(prob, X, Y, sess))
+    rounds = [sp for sp in spans if sp[0] == "api.fusedmm"]
+    host = [sp for sp in spans if sp[0] == "als.cg_host"]
+    assert len(rounds) == len(host) == 3
+    for rnd, h, nxt in zip(rounds, host, rounds[1:] + [None]):
+        assert rnd[2] <= h[1] and (nxt is None or h[2] <= nxt[1])
+        assert not _inside(h, spans, "api.put")
+    hits = [k[3]["hit"] for k in spans if k[0] == "api.session_key"]
+    assert len(hits) == 6 and hits[1] == 0 and hits[3] == hits[5] == 1
+
+
+def test_profiler_spans_leave_results_bitwise_identical(tmp_path):
+    prob, X, Y = _problem(algorithm="s15")
+
+    def work():
+        out, rv = prob.fusedmm(X, Y, elision="fused",
+                               session=api.Session())
+        return out, rv.values(), _cg(prob, X, Y, api.Session())
+
+    base = work()
+    traced, spans = _profiled(tmp_path, work)
+    assert spans
+    for a, b in zip(base, traced, strict=True):
+        assert np.isfinite(a).all() and np.array_equal(a, b)
+
+
 def test_chrome_trace_structure_and_artifacts(tmp_path):
+    """The profiler's Perfetto export is Chrome trace-event JSON with the
+    program's spans as complete events, nested on one thread's track;
+    ``write_artifacts`` writes the metrics snapshot beside it."""
     prob, X, Y = _problem(algorithm="d15")
     with obs.collect() as reg, obs.trace(measure_wire=False) as tr:
-        prob.sddmm(X, Y)
-    ct = obs.chrome_trace(tr)
-    evs = ct["traceEvents"]
-    names = {e["name"] for e in evs}
-    assert "d15.sddmm" in names and "rank 0" in str(evs)
-    xs = [e for e in evs if e["ph"] == "X"]
-    assert xs and all(set(e) >= {"ts", "dur", "pid", "tid"} for e in xs)
-    # events nest inside their round span on the same track
-    rnd = next(e for e in xs if e["cat"] == "round")
-    for e in xs:
-        if e["cat"] == "event" and e["tid"] == rnd["tid"]:
-            assert e["ts"] >= rnd["ts"] - 1e-6
-            assert e["ts"] + e["dur"] <= rnd["ts"] + rnd["dur"] + 1e-6
-    paths = obs.write_artifacts(str(tmp_path), "t", tracer=tr,
-                                registry=reg)
-    trace_blob = json.load(open(paths["trace"]))
-    assert trace_blob["traceEvents"]
+        _profiled(tmp_path / "trace", lambda: prob.sddmm(X, Y), perfetto=True)
+    (path,) = (tmp_path / "trace").glob("plugins/profile/*/perfetto_trace"
+                                        ".json.gz")
+    evs = json.load(gzip.open(path))["traceEvents"]
+    xs = {e["name"]: e for e in evs if e.get("ph") == "X"
+          and e["name"].startswith("api.")}
+    assert set(xs) >= {"api.sddmm", "api.put", "api.upload",
+                       "api.assemble"}
+    rnd = xs["api.sddmm"]
+    assert rnd["args"] == {"family": "d15", "elision": "none"}
+    for e in xs.values():
+        assert e["tid"] == rnd["tid"]
+        assert rnd["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= rnd["ts"] + rnd["dur"] + 1e-3
+    # the tracer's round duration is measured around the same call
+    assert 0 < tr.rounds[0].dur
+    paths = obs.write_artifacts(str(tmp_path), "t", registry=reg)
+    assert set(paths) == {"metrics"}
+    assert paths["metrics"].endswith("METRICS_t.json")
     metrics_blob = json.load(open(paths["metrics"]))
     assert obs.MetricsRegistry.from_snapshot(
         metrics_blob).snapshot() == reg.snapshot()
-    assert paths["trace"].endswith("TRACE_t.json")
-    assert paths["metrics"].endswith("METRICS_t.json")
+    assert not hasattr(obs, "chrome_trace")
 
 
 def test_round_summary_renders():
