@@ -43,9 +43,13 @@ the layout-aware fast path for callers that keep data device-resident.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import contextlib
 import dataclasses
+import functools
 import hashlib
+import os
+import threading
 import time
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -1239,6 +1243,71 @@ class DistProblem:
 # Session: across-call replication reuse
 # ---------------------------------------------------------------------------
 
+_FP_CHUNK = 4 << 20        # bytes one pool worker hashes and sums at a time
+_FP_SERIAL = 8 << 20       # operands smaller than this stay on the caller
+_fp_pool = None
+_fp_pool_lock = threading.Lock()
+
+
+def _fp_workers() -> concurrent.futures.ThreadPoolExecutor:
+    """The shared thread pool of the chunked fingerprints, made on first
+    use.  hashlib and numpy's reductions release the GIL on large
+    buffers, so the chunks run on as many cores as there are workers."""
+    global _fp_pool
+    with _fp_pool_lock:
+        if _fp_pool is None:
+            _fp_pool = concurrent.futures.ThreadPoolExecutor(
+                min(8, os.cpu_count() or 1),
+                thread_name_prefix="session-fingerprint")
+        return _fp_pool
+
+
+def _chunk_fp(chunk: np.ndarray, digest: bool, total: bool):
+    """(16-byte blake2b, float64 sum) of one contiguous flat chunk; each
+    part None where not wanted."""
+    return (hashlib.blake2b(chunk.view(np.uint8), digest_size=16).digest()
+            if digest else None,
+            float(chunk.sum(dtype=np.float64)) if total else None)
+
+
+def _fingerprints(arr, *, digest: bool = True, total: bool = True):
+    """``(digest, total)`` of an operand's contents: a 128-bit blake2b hex
+    digest over every byte in C order, and the float64 sum the Session's
+    memo re-checks a numpy operand by; each None where not wanted.
+
+    Both are read straight from the operand's buffer (a non-contiguous
+    operand is copied once, contiguous).  Below ``_FP_SERIAL`` bytes one
+    pass on the caller's thread; from there, fixed ``_FP_CHUNK``-byte
+    chunks on the shared pool, the digest a blake2b over the shape, the
+    dtype and the chunks' digests in order, the sum the chunks' sums
+    added in order.  Both depend on the contents alone, never on the
+    number of workers.  The digest runs in an ``api.digest`` span."""
+    a = np.ascontiguousarray(np.asarray(arr))
+    flat = a.reshape(-1)
+    if a.nbytes < _FP_SERIAL:
+        chunks = [flat]
+    else:
+        per = _FP_CHUNK // a.itemsize
+        chunks = [flat[i:i + per] for i in range(0, flat.size, per)]
+    span = (_span("api.digest", bytes=a.nbytes, chunks=len(chunks))
+            if digest else contextlib.nullcontext())
+    with span:
+        if len(chunks) == 1:
+            d, s = _chunk_fp(chunks[0], digest, total)
+            return (d.hex() if digest else None), s
+        parts = list(_fp_workers().map(
+            functools.partial(_chunk_fp, digest=digest, total=total),
+            chunks))
+    hexdigest = None
+    if digest:
+        h = hashlib.blake2b(repr((a.shape, str(a.dtype))).encode(),
+                            digest_size=16)
+        for d, _ in parts:
+            h.update(d)
+        hexdigest = h.hexdigest()
+    return hexdigest, (sum(s for _, s in parts) if total else None)
+
+
 class Session:
     """Caches fiber-replicated dense operands across executor calls.
 
@@ -1258,7 +1327,13 @@ class Session:
     replicate the changing iterate through the session too, and without
     eviction every iterate's device copy would stay pinned for the
     session's lifetime.  The stationary operand is hit on every call and
-    therefore never ages out."""
+    therefore never ages out.
+
+    The digest is a 128-bit blake2b over the operand's own buffer, with
+    no copy of a contiguous operand; from 8 MiB it is a hash tree of
+    blake2b over fixed 4 MiB chunks, hashed on a small shared thread
+    pool (``_fingerprints``).  The memo's float64 sum is taken the same
+    way, in the same pass where both are needed."""
 
     def __init__(self, max_entries: int = 16):
         self._cache = collections.OrderedDict()
@@ -1268,31 +1343,35 @@ class Session:
         self.misses = 0
 
     @staticmethod
-    def _key(problem: "DistProblem", arr, slot: str):
+    def _key(problem: "DistProblem", arr, slot: str, total: bool = False):
+        """(content key, the operand's float64 sum if ``total``)."""
         # comm mode is part of the key: replication state cached for a
         # dense-wire problem is never served to a sparse-wire one (the
         # pre-gathered layouts coincide today, but the key must not bake
         # that implementation detail in)
         a = np.asarray(arr)
-        digest = hashlib.blake2b(a.tobytes(), digest_size=16).hexdigest()
+        digest, s = _fingerprints(a, total=total)
         return (id(problem.grid), problem.alg.name, problem.comm, slot,
-                a.shape, str(a.dtype), digest)
+                a.shape, str(a.dtype), digest), s
 
     @staticmethod
-    def _cheap_fp(arr):
+    def _cheap_fp(arr, total: Optional[float] = None):
         # mutation check for numpy operands on the id fast path; jax
-        # arrays are immutable, so identity alone is sound for them
+        # arrays are immutable, so identity alone is sound for them.
+        # ``total`` is the operand's sum where the digest's pass took it
         if isinstance(arr, np.ndarray):
-            return (arr.shape, str(arr.dtype),
-                    float(arr.sum(dtype=np.float64)))
+            if total is None:
+                total = _fingerprints(arr, digest=False)[1]
+            return (arr.shape, str(arr.dtype), total)
         return None
 
     def _content_key(self, problem: "DistProblem", arr, slot: str):
         """Content key with an identity fast path: the iterating caller
         (ALS's CG loop) passes the SAME host array object every call,
-        so the full tobytes+digest — a device sync for jax operands —
-        is paid once, not per hit; the memo verifies numpy operands by
-        a cheap sum fingerprint so in-place mutation still re-keys.
+        so the full digest — a device sync for jax operands — is paid
+        once, not per hit; the memo verifies numpy operands by a cheap
+        sum fingerprint so in-place mutation still re-keys.  An operand
+        the memo does not hold gets its digest and sum in one pass.
         The memo holds only WEAK references (no operand pinning) and
         evicts LRU per entry; an id is validated by dereferencing the
         weakref, so id recycling after gc cannot alias a dead entry."""
@@ -1300,13 +1379,17 @@ class Session:
             memo_k = (id(problem.grid), problem.alg.name, problem.comm,
                       slot, id(arr))
             memo = self._id_memo.get(memo_k)
-            fp = self._cheap_fp(arr)
-            hit = memo is not None and memo[0]() is arr and memo[2] == fp
+            live = memo is not None and memo[0]() is arr
+            fp = self._cheap_fp(arr) if live else None
+            hit = live and memo[2] == fp
             span.set_metadata(hit=hit)
             if hit:
                 self._id_memo.move_to_end(memo_k)
                 return memo[1]
-            key = self._key(problem, arr, slot)
+            numpy_op = not live and isinstance(arr, np.ndarray)
+            key, total = self._key(problem, arr, slot, total=numpy_op)
+            if not live:
+                fp = self._cheap_fp(arr, total)
             try:
                 ref = weakref.ref(arr)
             except TypeError:

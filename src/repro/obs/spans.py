@@ -16,6 +16,8 @@ The program's spans (docs/observability.md):
     api.put           the family's operand placement for the round
     api.session_key   ``Session``'s content key of one operand; ``hit``
                       when the identity memo answered without a digest
+    api.digest        a missed key's blake2b of the operand; ``bytes``,
+                      ``chunks`` (hashed on a thread pool from 8 MiB)
     api.upload        one host-to-device ``jax.device_put``; ``bytes``
     api.assemble      the host result's assembly
     api.wait          waiting for a device result about to be copied

@@ -6,6 +6,7 @@ every registered algorithm degenerates onto a single-device grid, which
 exercises the full plan/execute/assemble path and the dispatch logic
 cheaply on every PR.
 """
+import concurrent.futures
 import inspect
 
 import numpy as np
@@ -324,6 +325,54 @@ def test_session_content_keyed_replay():
     out_mut, _ = prob.fusedmm(X, Ymut, elision="reuse", session=sess)
     want, _ = prob.fusedmm(X, Ymut, elision="reuse")
     np.testing.assert_array_equal(want, out_mut)
+
+
+@pytest.mark.parametrize("nbytes", [
+    api._FP_SERIAL - 256,                  # one serial pass
+    api._FP_SERIAL,                        # the first chunked size
+    3 * api._FP_CHUNK + 5 * 256,           # four chunks, the last short
+])
+def test_session_fingerprints_over_sizes(nbytes, monkeypatch):
+    """The Session's content key and memo sum, read from the operand's
+    buffer serially or in chunks on the pool: equal contents key alike
+    whatever their layout or the pool's size; one changed element, a
+    shape or a dtype re-keys."""
+    rows, cols, vals, _, _, _ = _problem_data(seed=13)
+    prob = _make(rows, cols, vals, (64, 64), 8, algorithm="d15")
+    rng = np.random.default_rng(nbytes)
+    A = rng.standard_normal((nbytes // 256, 64)).astype(np.float32)
+    assert A.nbytes == nbytes
+    sess = api.Session()
+    key = sess._content_key(prob, A, "x")
+    assert key[-1] == api._fingerprints(A)[0]
+    # a content-equal copy, a non-contiguous view of the same values and
+    # a Fortran-ordered copy key alike
+    assert api.Session()._content_key(prob, A.copy(), "x") == key
+    wide = np.repeat(A, 2, axis=1)
+    assert api.Session()._content_key(prob, wide[:, ::2], "x") == key
+    assert api.Session()._content_key(prob, np.asfortranarray(A),
+                                      "x") == key
+    # the same bytes under another shape or dtype key apart
+    for other in (A.reshape(-1, 32), A.view(np.int32)):
+        assert api.Session()._content_key(prob, other, "x") != key
+    # the digest's pass and the memo's own take the same sum
+    digest, total = api._fingerprints(A)
+    assert api._fingerprints(A, digest=False) == (None, total)
+    assert np.isclose(total, A.sum(dtype=np.float64), rtol=1e-9)
+    # neither depends on the pool's size
+    for workers in (1, 4):
+        with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+            monkeypatch.setattr(api, "_fp_pool", pool)
+            assert api._fingerprints(A) == (digest, total)
+    monkeypatch.undo()
+    # one element of the last chunk, changed in place: the memo's sum
+    # moves and the key follows
+    fp = api.Session._cheap_fp(A)
+    A[-1, -1] += 1.0
+    assert api.Session._cheap_fp(A) != fp
+    assert sess._content_key(prob, A, "x") != key
+    assert sess._content_key(prob, A, "x") == api.Session()._content_key(
+        prob, A.copy(), "x")
 
 
 def test_with_values_and_transposed():

@@ -312,8 +312,18 @@ def test_profiler_spans_of_a_cg_solve(tmp_path):
     for rnd, h, nxt in zip(rounds, host, rounds[1:] + [None]):
         assert rnd[2] <= h[1] and (nxt is None or h[2] <= nxt[1])
         assert not _inside(h, spans, "api.put")
-    hits = [k[3]["hit"] for k in spans if k[0] == "api.session_key"]
+    keys = [k for k in spans if k[0] == "api.session_key"]
+    hits = [k[3]["hit"] for k in keys]
     assert len(hits) == 6 and hits[1] == 0 and hits[3] == hits[5] == 1
+    # the digest runs once per memo miss, inside that key, over the
+    # operand's bytes (X and the iterates share one shape)
+    digests = [sp for sp in spans if sp[0] == "api.digest"]
+    assert len(digests) == hits.count(0)
+    for k in keys:
+        inner = _inside(k, spans, "api.digest")
+        assert len(inner) == (1 - k[3]["hit"])
+        assert all(d[3]["bytes"] == X.nbytes and d[3]["chunks"] == 1
+                   for d in inner)
 
 
 def test_profiler_spans_leave_results_bitwise_identical(tmp_path):
